@@ -46,10 +46,9 @@ Five regimes probe the cost model's axes (message width, degree skew):
   path, and the class layout must not regress the single-ELL packing
   (asserted).
 
-On a native-Pallas host (TPU) the per-class grids change the picture
-further (class-local ``max_blocks`` stops tail tiles from paying hub
-grid extents); asserts here are calibrated for the XLA (ELL) lowering
-CI actually runs.
+Asserts here are calibrated for the XLA (ELL) lowering, the one
+every platform runs; the Pallas kernel is reachable only through
+``REPRO_DELIVERY_LOWERING``.
 
 Writes ``BENCH_delivery.json`` (uploaded by the nightly CI job).
 """

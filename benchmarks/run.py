@@ -9,6 +9,9 @@ import traceback
 
 
 def main() -> None:
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
     from benchmarks import (
         bench_delivery,
         bench_loc,

@@ -32,13 +32,13 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import shard_map as _shard_map
 from repro.core.api import Program, constant_initial_msg
 from repro.core.engine import _as_out, batch_halting_scan
 from repro.core.hypergraph import HyperGraph
 from repro.partition.base import PartitionPlan
 
 Pytree = Any
+_shard_map = partial(jax.shard_map, check_vma=False)
 
 
 def _pad_to(n: int, parts: int) -> int:
@@ -578,10 +578,11 @@ def build_distributed_runner(
         )
         return v_a, he_a, v_tr, he_tr, executed
 
-    # replication checking off: the halt flag is partition-uniform by
-    # construction, which 0.4.x check_rep cannot prove.  The activity
-    # traces are likewise partition-uniform (psum'd / computed on the
-    # replicated full-size buffers), so their out_spec is P().
+    # Varying-axes checking off (``_shard_map``): the halt flag is
+    # partition-uniform by construction, which the checker cannot
+    # prove.  The activity traces are likewise partition-uniform
+    # (psum'd / computed on the replicated full-size buffers), so their
+    # out_spec is P().
     if resumable:
         if batch is not None:
             raise ValueError("resumable runner is unbatched")

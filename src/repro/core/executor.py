@@ -27,7 +27,7 @@ from __future__ import annotations
 
 import dataclasses
 import time
-from collections import OrderedDict
+from collections import Counter, OrderedDict
 from typing import Any, Callable, Mapping
 
 import jax
@@ -36,6 +36,7 @@ import numpy as np
 from repro.core.clique import clique_expansion_size, to_graph
 from repro.core.engine import compute, compute_jit
 from repro.core.hypergraph import HyperGraph
+from repro.core.serving import AotExecutable
 from repro.obs.calibrate import (
     delivery_traffic_pair,
     executed_supersteps,
@@ -461,32 +462,28 @@ def select_delivery(spec, hg: HyperGraph) -> tuple[str, dict]:
     layout, so custom ``reducer``s / ``edge_transform``s (which consume
     materialized per-incidence rows) and empty structures take ``xla``.
 
-    Then per lowering (``repro.kernels.deliver.select_lowering``):
-
-    * ``pallas`` (native TPU): fused delivery reads each message row
-      once per incident edge instead of gather+mask+re-read (~3x HBM
-      traffic) — always projected to win on the monoid path.
-    * ``ell`` (XLA hosts): the win comes from replacing the serialized
-      scatter with dense reduces, and dies by padding.  The padding
-      term is the degree-class plan's summed work
-      (``plan_degree_classes`` over both directions' live-degree
-      histograms — dense slots at the builder's pow2 row padding
-      (``ClassPlan.built_work``) plus residual; exactly what a layout
-      built by the LOCAL builder allocates, so model and builder
-      cannot disagree there.  The distributed builder plans from
-      merged per-shard histograms and harmonizes pads to shard maxima,
-      so its realized padded work can exceed this estimate on
-      shard-skewed cuts — the budget is a lower bound in that case).
-      Pick
-      fused while (a) class padding is bounded
-      (``FUSED_ELL_WORK_BUDGET`` slots per incidence, both directions)
-      and (b) the message row is within ``FUSED_MAX_WIDTH_BYTES`` —
-      a boundary the class layout MOVED: at 64-byte rows under zipf
-      skew the PR-4 single-ELL packing measured a ~2x loss to the
-      reference (overflow scatter), while per-class widths keep hubs
-      dense and win the regime.  The reported ``skew_gain`` (single-ELL
-      vs class plan, residual-weighted) quantifies how much of the
-      decision the degree classes carry.
+    Then one cost model on every platform; ``why["lowering"]`` names
+    the lowering the fused path will run
+    (``repro.kernels.deliver.select_lowering``: ``ell``, the XLA
+    sliced-ELL form, unless overridden).  The fused win comes from
+    replacing the serialized scatter with dense reduces, and dies by
+    padding.  The padding term is the degree-class plan's summed work
+    (``plan_degree_classes`` over both directions' live-degree
+    histograms — dense slots at the builder's pow2 row padding
+    (``ClassPlan.built_work``) plus residual; exactly what a layout
+    built by the LOCAL builder allocates, so model and builder cannot
+    disagree there.  The distributed builder plans from merged
+    per-shard histograms and harmonizes pads to shard maxima, so its
+    realized padded work can exceed this estimate on shard-skewed cuts
+    — the budget is a lower bound in that case).  Pick fused while
+    (a) class padding is bounded (``FUSED_ELL_WORK_BUDGET`` slots per
+    incidence, both directions) and (b) the message row is within
+    ``FUSED_MAX_WIDTH_BYTES`` — a boundary the class layout MOVED: at
+    64-byte rows under zipf skew the PR-4 single-ELL packing measured
+    a ~2x loss to the reference (overflow scatter), while per-class
+    widths keep hubs dense and win the regime.  The reported
+    ``skew_gain`` (single-ELL vs class plan, residual-weighted)
+    quantifies how much of the decision the degree classes carry.
     """
     reason = _non_monoid_reason(spec)
     why: dict[str, Any] = {}
@@ -497,15 +494,7 @@ def select_delivery(spec, hg: HyperGraph) -> tuple[str, dict]:
         why["reason"] = "empty structure"
         return "xla", why
 
-    lowering = select_lowering()
-    why["lowering"] = lowering
-    if lowering != "ell":
-        why["reason"] = (
-            "native pallas lowering: fused path streams each message "
-            "row once (vs 3x reference HBM traffic)"
-        )
-        return "pallas_fused", why
-
+    why["lowering"] = select_lowering()
     live = (
         np.asarray(hg.e_mask) != 0
         if hg.e_mask is not None
@@ -1430,9 +1419,13 @@ class Engine:
         ``exec_cache_size``).  ``entry_shapes`` describes each live
         entry's bucket (algorithm, padded dims, batch bucket, design
         point) so an operator can see WHAT the cache holds, not just how
-        much; ``disk`` mirrors the attached persistent store's counters
+        much; ``sources`` counts live entries by origin (``aot`` |
+        ``disk``); ``disk`` mirrors the attached persistent store's counters
         (``None`` without one).
         """
+        sources = Counter(getattr(e, "source", None)
+                          for e in self._exec_cache.values())
+        sources.pop(None, None)
         return {
             "entries": len(self._exec_cache),
             "capacity": self.exec_cache_size,
@@ -1440,6 +1433,7 @@ class Engine:
             "misses": self._cache_misses,
             "evictions": self._cache_evictions,
             "traces": self._trace_count,
+            "sources": dict(sources),
             "entry_shapes": [
                 dict(meta) for meta in self._exec_meta.values()
             ],
@@ -1480,6 +1474,8 @@ class Engine:
                 exe = build()
         if self.disk_cache is not None:
             exe = self.disk_cache.wrap(self, key, exe)
+        else:
+            exe = AotExecutable(exe)
         cache[key] = exe
         if meta is not None:
             self._exec_meta[key] = meta

@@ -24,6 +24,7 @@ bitwise identical to an unpadded run while shapes stay bucket-stable.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import time
 from typing import Any
@@ -335,18 +336,34 @@ def _pad_shards(plan, shard_len_pad: int):
     )
 
 
-def _warm_executable(exe, args: tuple) -> str:
-    """Materialize one executable without serving a request.
+class AotExecutable:
+    """One Engine LRU entry: a jitted builder output, compiled by
+    ``warm(args)`` before its first call, so a lowering or compile error
+    surfaces in its own phase and never degrades (``_serve``) nor falls
+    back to plain jit.  ``source``: ``aot`` (compiled here) or ``disk``
+    (deserialized by ``repro.serve.cache``).
+    """
 
-    Disk-backed executables (``repro.serve.cache``) resolve their
-    deserialize-vs-AOT-compile choice here and report which path won;
-    plain jitted executables warm by executing once (the compile is the
-    point — the discarded result costs one padded batch)."""
-    warm_fn = getattr(exe, "warm", None)
-    if warm_fn is not None:
-        return warm_fn(args)
-    jax.block_until_ready(exe(*args))
-    return "jit"
+    __slots__ = ("jitted", "compiled", "source")
+
+    def __init__(self, jitted):
+        self.jitted = jitted
+        self.compiled = None
+        self.source = None
+
+    def _materialize(self, args: tuple) -> None:
+        self.compiled = self.jitted.lower(*args).compile()
+        self.source = "aot"
+
+    def warm(self, args: tuple) -> str:
+        """Materialize without executing; returns the winning source."""
+        if self.compiled is None:
+            self._materialize(args)
+        return self.source
+
+    def __call__(self, *args):
+        self.warm(args)
+        return self.compiled(*args)
 
 
 # --------------------------------------------------------------------------
@@ -407,20 +424,11 @@ class CompiledAlgorithm:
             query = spec.query0
         if self.config.checkpoint_every is not None:
             return self._run_checkpointed(hg, query)
-        try:
-            prep = self._prepared(hg, rebind=query is not None)
-            q = _canon_query(query) if query is not None else None
-            return self._execute(prep, q, batch=None)
-        except ValueError:
-            raise
-        except Exception as err:
-            twin = (
-                self._degraded_sibling(err)
-                if not is_transient(err) else None
-            )
-            if twin is None:
-                raise
-            return twin.run(hg, query=query)
+        q = _canon_query(query) if query is not None else None
+        return self._serve(
+            hg, q, None, rebind=query is not None,
+            retry=lambda twin: twin.run(hg, query=query),
+        )
 
     def run_batch(self, queries: Any, hg: HyperGraph | None = None):
         """Serve a batch: vmap the executable over the spec's query axis.
@@ -437,38 +445,29 @@ class CompiledAlgorithm:
                 f"spec {self.spec.name!r} has no bind_query: declare the "
                 "per-request axis to serve batched queries"
             )
-        try:
-            prep = self._prepared(hg, rebind=True)
-            queries_c = _canon_query(queries)
-            sizes = {
-                int(jnp.shape(leaf)[0])
-                for leaf in jax.tree.leaves(queries_c)
-            }
-            if len(sizes) != 1:
-                raise ValueError(
-                    f"query leaves disagree on batch size: {sorted(sizes)}"
-                )
-            b = sizes.pop()
-            b_pad = bucket_dim(b, floor=BATCH_FLOOR)
-            # Repeat-pad with the last query: always a *valid* request,
-            # and the padded rows are sliced off the results.
-            queries_p = jax.tree.map(
-                lambda leaf: jnp.concatenate(
-                    [leaf] + [leaf[-1:]] * (b_pad - b)
-                ) if b_pad > b else leaf,
-                queries_c,
+        queries_c = _canon_query(queries)
+        sizes = {
+            int(jnp.shape(leaf)[0])
+            for leaf in jax.tree.leaves(queries_c)
+        }
+        if len(sizes) != 1:
+            raise ValueError(
+                f"query leaves disagree on batch size: {sorted(sizes)}"
             )
-            return self._execute(prep, queries_p, batch=(b, b_pad))
-        except ValueError:
-            raise
-        except Exception as err:
-            twin = (
-                self._degraded_sibling(err)
-                if not is_transient(err) else None
-            )
-            if twin is None:
-                raise
-            return twin.run_batch(queries, hg=hg)
+        b = sizes.pop()
+        b_pad = bucket_dim(b, floor=BATCH_FLOOR)
+        # Repeat-pad with the last query: always a *valid* request,
+        # and the padded rows are sliced off the results.
+        queries_p = jax.tree.map(
+            lambda leaf: jnp.concatenate(
+                [leaf] + [leaf[-1:]] * (b_pad - b)
+            ) if b_pad > b else leaf,
+            queries_c,
+        )
+        return self._serve(
+            hg, queries_p, (b, b_pad), rebind=True,
+            retry=lambda twin: twin.run_batch(queries, hg=hg),
+        )
 
     def warmup(
         self,
@@ -488,7 +487,7 @@ class CompiledAlgorithm:
         eager compile.  ``query``: example request for specs whose
         ``query0`` is unset; required to warm query-bearing paths.
 
-        Returns ``{path: {"source": "disk"|"aot"|"jit"}}``.
+        Returns ``{path: {"source": "disk"|"aot"}}``.
         """
         spec = self.spec
         if query is None:
@@ -500,8 +499,8 @@ class CompiledAlgorithm:
         )
         prep = self._prepared(hg, rebind=has_query)
         q = _canon_query(query) if has_query else None
-        report = {"single": self._execute(prep, q, batch=None,
-                                          warm_only=True)}
+        exe, _ = self._materialize(prep, q, None)
+        report = {"single": {"source": exe.source}}
         for b in batch_sizes:
             if spec.bind_query is None:
                 raise ValueError(
@@ -520,12 +519,32 @@ class CompiledAlgorithm:
                 ),
                 q,
             )
-            report[f"batch{b_pad}"] = self._execute(
-                prep, queries, batch=(b_pad, b_pad), warm_only=True
-            )
+            exe, _ = self._materialize(prep, queries, (b_pad, b_pad))
+            report[f"batch{b_pad}"] = {"source": exe.source}
         return report
 
     # -- fault tolerance ---------------------------------------------------
+
+    def _serve(self, hg, query, batch, *, rebind: bool, retry):
+        """Prepare, compile, execute.  A layout-build or execute failure
+        may degrade to the ``xla`` twin (``retry(twin)``); a lowering or
+        compile error never does — a silent switch would hide which
+        path the device runs."""
+        stage = "prepare"
+        try:
+            prep = self._prepared(hg, rebind=rebind)
+            stage = "compile"
+            exe, args = self._materialize(prep, query, batch)
+            stage = "execute"
+            return self._execute(prep, query, batch, exe, args)
+        except Exception as err:
+            twin = None
+            if (stage != "compile" and not isinstance(err, ValueError)
+                    and not is_transient(err)):
+                twin = self._degraded_sibling(err)
+            if twin is None:
+                raise
+            return retry(twin)
 
     def _degraded_sibling(self, err: Exception):
         """Graceful-degradation chain, delivery link: a ``pallas_fused``
@@ -753,18 +772,15 @@ class CompiledAlgorithm:
         )
         return plan
 
-    def _execute(self, prep: dict, query, batch, warm_only: bool = False):
-        from repro.core.executor import Result
-
+    def _materialize(self, prep: dict, query, batch):
+        """The compile phase: this request's executable, compiled (or
+        loaded from disk) before anything executes, and its args."""
         cfg = self.config
         spec = self.spec
         engine = self.engine
-        distributed = cfg.backend != "local"
         has_query = query is not None
-        b, b_pad = batch if batch is not None else (None, None)
+        b_pad = batch[1] if batch is not None else None
 
-        base, hgp, plan = prep["base"], prep["hgp"], prep["plan"]
-        nv, ne = prep["nv"], prep["ne"]
         v_sig, he_sig, e_sig = prep["attr_sigs"]
         one_query = (
             jax.tree.map(lambda leaf: leaf[0], query)
@@ -794,12 +810,52 @@ class CompiledAlgorithm:
             "batch_pad": b_pad,
             "n_parts": prep["n_parts"],
         }
+        real = (
+            jnp.asarray(prep["nv"], jnp.int32),
+            jnp.asarray(prep["ne"], jnp.int32),
+        )
+        if cfg.backend != "local":
+            exe = engine._executable_for(
+                key,
+                lambda: _build_distributed_executable(
+                    spec, cfg, engine.mesh, prep["n_parts"],
+                    prep["nv_pad"], prep["ne_pad"],
+                    has_query, b_pad, engine._note_trace,
+                ),
+                meta=meta,
+            )
+            args = (prep["hgp"], *prep["shards"], prep["delivery"],
+                    *real, query)
+            with engine.mesh:
+                exe.warm(args)
+        else:
+            exe = engine._executable_for(
+                key,
+                lambda: _build_local_executable(
+                    spec, cfg, has_query, b_pad, engine._note_trace,
+                ),
+                meta=meta,
+            )
+            args = (prep["hgp"], prep["delivery"], *real, query)
+            exe.warm(args)
+        return exe, args
+
+    def _execute(self, prep: dict, query, batch, exe, args):
+        from repro.core.executor import Result
+
+        cfg = self.config
+        spec = self.spec
+        engine = self.engine
+        distributed = cfg.backend != "local"
+        b = batch[0] if batch is not None else None
+        base, plan = prep["base"], prep["plan"]
+        nv, ne = prep["nv"], prep["ne"]
 
         # Fault injection on the execute seam: one attribute load and a
         # None-check when no injector is attached (the same zero-overhead
         # contract as the tracer below).  Warmup never "executes".
         inj = getattr(engine, "fault_injector", None)
-        if inj is not None and not warm_only:
+        if inj is not None:
             inj.maybe_raise(
                 "execute", algorithm=spec.name, backend=cfg.backend,
                 delivery=cfg.delivery,
@@ -809,12 +865,12 @@ class CompiledAlgorithm:
             )
 
         # Tracing on the serve hot path is strictly opt-in: without a
-        # tracer this closure is exactly ``exe(*args)`` — no timing, no
+        # tracer this is exactly ``exe(*args)`` — no timing, no
         # allocation (the zero-overhead contract bench_obs asserts).
         tracer = engine.tracer
         timing: dict = {}
 
-        def _call(exe, args):
+        def _call():
             if tracer is None:
                 return exe(*args)
             t0 = time.perf_counter()
@@ -831,44 +887,8 @@ class CompiledAlgorithm:
             timing["device_wait_s"] = sp.args.get("device_wait_s", 0.0)
             return out
 
-        if distributed:
-            exe = engine._executable_for(
-                key,
-                lambda: _build_distributed_executable(
-                    spec, cfg, engine.mesh, prep["n_parts"],
-                    prep["nv_pad"], prep["ne_pad"],
-                    has_query, b_pad, engine._note_trace,
-                ),
-                meta=meta,
-            )
-            s_src, s_dst, s_mask = prep["shards"]
-            args = (
-                hgp, s_src, s_dst, s_mask, prep["delivery"],
-                jnp.asarray(nv, jnp.int32),
-                jnp.asarray(ne, jnp.int32),
-                query,
-            )
-            with engine.mesh:
-                if warm_only:
-                    return {"source": _warm_executable(exe, args)}
-                v_attr, he_attr, stats, executed = _call(exe, args)
-        else:
-            exe = engine._executable_for(
-                key,
-                lambda: _build_local_executable(
-                    spec, cfg, has_query, b_pad, engine._note_trace,
-                ),
-                meta=meta,
-            )
-            args = (
-                hgp, prep["delivery"],
-                jnp.asarray(nv, jnp.int32),
-                jnp.asarray(ne, jnp.int32),
-                query,
-            )
-            if warm_only:
-                return {"source": _warm_executable(exe, args)}
-            v_attr, he_attr, stats, executed = _call(exe, args)
+        with engine.mesh if distributed else contextlib.nullcontext():
+            v_attr, he_attr, stats, executed = _call()
 
         # Slice padding (and batch padding) back off; extract on a
         # real-size hypergraph whose attrs may carry a leading batch dim

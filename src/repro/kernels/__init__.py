@@ -3,8 +3,9 @@
 deliver/ - fused incidence delivery: scalar-prefetch gather + mask +
            segment-combine over a dst-sorted CSR layout (the whole
            half-superstep data path; the ``delivery='pallas_fused'``
-           design point), with an equivalent ELL+COO XLA lowering for
-           hosts without a native Pallas backend.
+           design point), with the equivalent ELL+COO XLA lowering
+           that every platform runs (Mosaic refuses the kernel's
+           in-kernel row gather; see ``deliver/__init__.py``).
 segsum/  - segment-sum as blocked one-hot matmul on the MXU (the MESH
            combine step: scatter-reduce -> dense systolic work);
            unsorted-fallback reference for the fused deliver kernel.

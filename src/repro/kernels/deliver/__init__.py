@@ -4,16 +4,17 @@
 ``DeliveryLayout`` is supplied (the ``delivery='pallas_fused'`` design
 point).  One fused data path, two lowerings:
 
+* ``ell`` — the layout driven through stock XLA ops
+  (``xla.deliver_ell_leaf``): dense ELL reduce + sorted-COO overflow.
+  The lowering on every platform, the TPU included;
 * ``pallas`` — the scalar-prefetch gather + mask + segment-combine
-  kernel (``fused.deliver_fused_pallas``), native on TPU, exercised in
-  interpret mode by the test suite;
-* ``ell`` — the identical layout driven through stock XLA ops
-  (``xla.deliver_ell_leaf``): dense ELL reduce + sorted-COO overflow,
-  the fast path on hosts without a native Pallas backend.
-
-``select_lowering`` picks per platform; ``REPRO_DELIVERY_LOWERING``
-(``ell`` | ``pallas`` | ``pallas_interpret``) overrides for tests and
-experiments.
+  kernel (``fused.deliver_fused_pallas``), exercised in interpret mode
+  by the test suite.  Mosaic refuses its in-kernel row gather
+  (``jnp.take`` on a VMEM ref), and it holds the whole message table
+  in VMEM, which a full-size hypergraph overflows
+  (``repro.analysis.shapes.check_width_gate``).  So it is never
+  selected; ``REPRO_DELIVERY_LOWERING`` (``ell`` | ``pallas`` |
+  ``pallas_interpret``) reaches it for tests and experiments.
 """
 from __future__ import annotations
 
@@ -64,7 +65,7 @@ Pytree = Any
 
 
 def select_lowering() -> str:
-    """``pallas`` on TPU, ``ell`` elsewhere; env-overridable."""
+    """``ell`` on every platform; ``REPRO_DELIVERY_LOWERING`` overrides."""
     forced = os.environ.get("REPRO_DELIVERY_LOWERING")
     if forced:
         if forced not in ("ell", "pallas", "pallas_interpret"):
@@ -73,7 +74,7 @@ def select_lowering() -> str:
                 f"pallas_interpret, got {forced!r}"
             )
         return forced
-    return "pallas" if jax.default_backend() == "tpu" else "ell"
+    return "ell"
 
 
 def _pallas_leaf(leaf, layout, monoid, active, *, interpret):

@@ -44,9 +44,13 @@ costs a per-edge mask at runtime.
 
 The kernel is written for TPU (scalar prefetch via
 ``pltpu.PrefetchScalarGridSpec``; in-kernel row gather) and validated
-on CPU in interpret mode; ``repro.kernels.deliver.xla`` is the
-equivalent fused data path expressed to XLA for hosts without a native
-Pallas backend.
+on CPU in interpret mode only.  Compiled for a v5e, Mosaic refuses the
+in-kernel gather (``jnp.take`` on the VMEM message block: "Shape
+mismatch in input, indices and output"), and the whole ``[n_src+1, D]``
+table as one VMEM block overflows VMEM at full-size hypergraphs.  So
+``repro.kernels.deliver.select_lowering`` never picks it;
+``repro.kernels.deliver.xla`` is the fused data path every platform
+runs.
 """
 from __future__ import annotations
 

@@ -2,8 +2,8 @@
 
 Same algorithm as the Pallas class kernels in ``fused`` — mask folded
 into the layout, message rows read once, combine without a serialized
-scatter — but lowered through stock XLA ops for hosts without a native
-Pallas backend (CPU CI, GPU until a Triton port lands):
+scatter — but lowered through stock XLA ops.  This is the lowering
+every platform runs, the TPU included:
 
 * each degree class's incidences sit in its own dense ``[rows_c, k_c]``
   id table: one vectorized gather and one dense axis reduction per

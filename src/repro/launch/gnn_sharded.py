@@ -24,7 +24,6 @@ from __future__ import annotations
 import jax
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map as compat_shard_map
 from repro.sparse.segment import edge_sharded
 from repro.train.optimizer import AdamWConfig, adamw_update
 from repro.train.step import TrainState
@@ -65,12 +64,11 @@ def make_edge_sharded_step(mod, cfg, mesh, opt_cfg: AdamWConfig = None):
         params_spec = jax.tree.map(
             lambda x: P(*((None,) * getattr(x, "ndim", 0))), state.params
         )
-        sharded_loss = compat_shard_map(
+        sharded_loss = jax.shard_map(
             local_loss,
             mesh=mesh,
             in_specs=(params_spec, batch_spec(batch)),
             out_specs=P(),
-            check=True,
         )
         loss, grads = jax.value_and_grad(sharded_loss)(state.params, batch)
         new_params, new_opt, opt_metrics = adamw_update(

@@ -159,6 +159,10 @@ def main(argv=None) -> int:
     import jax
     import numpy as np
 
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
+
     from repro.core import AnalyticsSpec, Engine
     from repro.data import make_dataset
     from repro.launch.mesh import make_host_mesh

@@ -31,12 +31,22 @@ def make_production_mesh(*, multi_pod: bool = False):
 
 
 def make_host_mesh(n_devices: int | None = None, axis: str = "data"):
-    """Small mesh over whatever devices exist (tests / local runs)."""
+    """1-D mesh over the first ``n_devices`` devices (all by default).
+
+    Raises when fewer devices exist than were asked for: a silently
+    smaller mesh would run a different partitioning than requested."""
     import numpy as np
     from jax.sharding import Mesh
 
     devs = jax.devices()
     n = n_devices or len(devs)
+    if n > len(devs):
+        raise RuntimeError(
+            f"make_host_mesh({n}) needs {n} devices but only {len(devs)} "
+            f"{devs[0].platform} device(s) are visible; on CPU set "
+            f"XLA_FLAGS=--xla_force_host_platform_device_count={n} before "
+            "the first jax import"
+        )
     return Mesh(np.array(devs[:n]).reshape(n), (axis,))
 
 

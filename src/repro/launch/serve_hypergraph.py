@@ -42,6 +42,22 @@ import sys
 import time
 
 
+def serve_specs(hg, iters: int = 12) -> dict:
+    """The served paths, keyed as requests name them: SSSP from a
+    source vertex and the personalized random walk (PPR) from a seed.
+    PPR has no default query; ``WARM_QUERIES`` gives each path one."""
+    from repro import algorithms as alg
+
+    return {
+        "sssp": alg.shortest_paths_spec(hg, source=0, max_iters=iters),
+        "ppr": alg.random_walk_spec(hg, iters=iters),
+    }
+
+
+# One example query per served path, in ``serve_specs`` order.
+WARM_QUERIES = [0, 0]
+
+
 def build_paths(regime: str = "dblp", scale: float = 0.003,
                 seed: int = 0, iters: int = 12) -> dict:
     """Replica builder (``ReplicaConfig.builder`` target): constructs
@@ -49,17 +65,10 @@ def build_paths(regime: str = "dblp", scale: float = 0.003,
     crosses the spawn boundary — each replica regenerates the (seeded,
     deterministic) dataset and spec set locally, and ``stable_digest``
     re-keys them onto the same shared disk-store entries."""
-    from repro import algorithms as alg
     from repro.data import make_dataset
 
     hg = make_dataset(regime, scale=scale, seed=seed)
-    return {
-        "specs": {
-            "sssp": alg.shortest_paths_spec(hg, source=0, max_iters=iters),
-            "ppr": alg.random_walk_spec(hg, iters=iters),
-        },
-        "warm_queries": [0, 0],  # ppr has no query0; seed vertex 0
-    }
+    return {"specs": serve_specs(hg, iters), "warm_queries": WARM_QUERIES}
 
 
 def _parse(argv=None):
@@ -132,7 +141,10 @@ def main(argv=None) -> int:
     import jax
     import numpy as np
 
-    from repro import algorithms as alg
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
+
     from repro.core import Engine
     from repro.data import make_dataset
     from repro.launch.mesh import make_host_mesh
@@ -170,17 +182,13 @@ def main(argv=None) -> int:
         # each replica (and on the router for ``router.route``) instead.
         fault_injector=None if args.replicas > 0 else injector,
     )
-    specs = {
-        "sssp": alg.shortest_paths_spec(hg, source=0,
-                                        max_iters=args.iters),
-        "ppr": alg.random_walk_spec(hg, iters=args.iters),
-    }
+    specs = serve_specs(hg, args.iters)
 
     if args.warm:
         report = warm(
             engine, list(specs.values()),
             batch_sizes=(args.max_batch,),
-            queries=[0, 0],  # ppr has no query0; seed vertex 0
+            queries=WARM_QUERIES,
         )
         print(f"warm boot: {report['boot_s']:.3f}s, "
               f"{report['traces']} traces, "

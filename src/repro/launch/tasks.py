@@ -19,7 +19,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map as compat_shard_map
 from repro.configs.base import ArchSpec, ShapeSpec
 from repro.launch.mesh import dp_axes, flat_axes, total_devices
 from repro.train.optimizer import AdamWConfig
@@ -531,12 +530,11 @@ def build_recsys_task(spec: ArchSpec, shape: ShapeSpec, mesh,
             # lax.top_k's sort is not batch-partitionable (XLA all-gathers
             # the [B, V] scores; measured 1 TB/device) — shard_map it so
             # each device sorts only its own batch rows.
-            vals, idx = compat_shard_map(
+            vals, idx = jax.shard_map(
                 lambda sc: tuple(jax.lax.top_k(sc, 100)),
                 mesh=mesh,
                 in_specs=P(fa, None),
                 out_specs=(P(fa, None), P(fa, None)),
-                check=True,  # preserve jax.shard_map's checking default
             )(scores)
             return vals, idx
 

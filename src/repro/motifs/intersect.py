@@ -34,7 +34,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map as _shard_map
 from repro.core.hypergraph import HyperGraph
 
 INTERSECT_KERNELS = ("auto", "bitset", "merge")
@@ -240,11 +239,12 @@ def batch_intersections(
     def run(data, a, b, c):
         return _batch_tiled(data, a, b, c, **kw)
 
-    mapped = _shard_map(
+    mapped = jax.shard_map(
         run,
         mesh=mesh,
         in_specs=(P(), P(axis), P(axis), P(axis)),
         out_specs=P(axis),
+        check_vma=False,
     )
     with mesh:
         out = jax.jit(mapped)(index.data, ea_p, eb_p, ec_p)

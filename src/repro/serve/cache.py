@@ -17,7 +17,10 @@ module closes that gap:
   into the environment that produced it.  Where the platform cannot
   round-trip a serialized executable, ``store`` degrades to a
   *warmup record* — a marker telling the next boot to re-trace eagerly
-  rather than on first request — so ``warm`` keeps its contract.
+  rather than on first request — so ``warm`` keeps its contract.  The
+  degradation is reported, not silent: ``stats()`` counts it
+  (``disk_stores`` vs ``disk_errors``) and names the cause
+  (``last_store_error``).
 * ``warm(engine, specs)`` is the replica-boot API: compile every spec
   and materialize its executables — deserializing from disk (ZERO
   retraces, asserted by tests) or AOT-compiling and populating the
@@ -50,6 +53,7 @@ except ImportError:  # pragma: no cover - non-POSIX: publish stays atomic
 
 import numpy as np
 
+from repro.core.serving import AotExecutable
 from repro.obs.metrics import default_registry, weak_provider
 from repro.obs.trace import maybe_span
 
@@ -203,6 +207,9 @@ class DiskExecutableCache:
             "disk_migrated": 0,
             "disk_lock_waits": 0,
         }
+        # Why the last ``store`` wrote a warmup record instead of an
+        # executable (``None`` while every store round-tripped).
+        self.last_store_error: str | None = None
         # Duck-typed like Engine.tracer: Engine(fault_injector=...)
         # forwards its injector here so the disk.read / disk.write /
         # disk.deserialize chaos points fire inside the real try blocks.
@@ -370,6 +377,7 @@ class DiskExecutableCache:
             })
         except Exception as err:
             self._stats["disk_errors"] += 1
+            self.last_store_error = f"{type(err).__name__}: {err}"
             try:
                 self._write(digest, {
                     "format": _FORMAT_WARMUP,
@@ -392,27 +400,27 @@ class DiskExecutableCache:
         entries = 0
         if self.dir.is_dir():
             entries = sum(1 for _ in self.dir.glob("*.jexe"))
-        return {**self._stats, "entries": entries, "dir": str(self.dir)}
+        return {
+            **self._stats, "entries": entries, "dir": str(self.dir),
+            "last_store_error": self.last_store_error,
+        }
 
 
-class _DiskBackedExecutable:
+class _DiskBackedExecutable(AotExecutable):
     """An Engine LRU entry backed by the disk store.
 
     First use resolves, in order: deserialize from disk (no trace, no
     compile), else AOT ``lower().compile()`` + store for the next
-    process, else (unloweable args) fall back to the plain jitted
-    callable.  ``source`` records which path won, for observability.
+    process.  A lowering or compile error propagates: there is no
+    plain-jit fallback.  ``source`` records which path won.
     """
 
-    __slots__ = ("cache", "key", "jitted", "compiled", "source",
-                 "_engine_ref")
+    __slots__ = ("cache", "key", "_engine_ref")
 
     def __init__(self, cache: DiskExecutableCache, key, jitted, engine=None):
+        super().__init__(jitted)
         self.cache = cache
         self.key = key
-        self.jitted = jitted
-        self.compiled = None
-        self.source = None
         # weak: the Engine's LRU owns this object, never the reverse
         self._engine_ref = weakref.ref(engine) if engine is not None else None
 
@@ -425,8 +433,6 @@ class _DiskBackedExecutable:
         return getattr(engine, "fault_injector", None)
 
     def _materialize(self, args: tuple) -> None:
-        if self.compiled is not None:
-            return
         tracer = self._tracer()
         with maybe_span(tracer, "serve.disk_load", cat="compile") as sp:
             loaded = self.cache.load(self.key)
@@ -447,32 +453,13 @@ class _DiskBackedExecutable:
                     sp.args["source"] = "disk"
                 return
             with maybe_span(tracer, "serve.aot_compile", cat="compile") as sp:
-                try:
-                    inj = self._injector()
-                    if inj is not None:
-                        inj.maybe_raise("compile.aot")
-                    compiled = self.jitted.lower(*args).compile()
-                except Exception:
-                    # Can't AOT-lower these args (exotic pytrees,
-                    # platform quirks): serve through plain jit, skip
-                    # persistence.
-                    self.compiled, self.source = self.jitted, "jit"
-                    if sp is not None:
-                        sp.args["source"] = "jit"
-                    return
-                self.compiled, self.source = compiled, "aot"
+                inj = self._injector()
+                if inj is not None:
+                    inj.maybe_raise("compile.aot")
+                super()._materialize(args)
                 if sp is not None:
-                    sp.args["source"] = "aot"
-            self.cache.store(self.key, compiled)
-
-    def warm(self, args: tuple) -> str:
-        """Materialize without executing; returns the winning source."""
-        self._materialize(args)
-        return self.source
-
-    def __call__(self, *args):
-        self._materialize(args)
-        return self.compiled(*args)
+                    sp.args["source"] = self.source
+            self.cache.store(self.key, self.compiled)
 
 
 # --------------------------------------------------------------------------
@@ -503,8 +490,8 @@ def warm(
 
         {"boot_s": ..., "traces": ..., "paths": {name: {path: source}}}
 
-    where each source is ``disk`` (deserialized), ``aot`` (compiled +
-    stored), or ``jit`` (no disk cache attached / unloweable).
+    where each source is ``disk`` (deserialized) or ``aot`` (compiled,
+    and stored when a disk cache is attached).
 
     ``require_no_retrace=True`` wraps the boot in the analysis-layer
     retrace sentinel: a replica that was expected to come up entirely

@@ -254,8 +254,30 @@ def _chaos_gate(injector, config: ReplicaConfig) -> bool:
     return True
 
 
+def held_accelerator() -> str | None:
+    """The non-CPU platform whose backend THIS process has already
+    initialized, or ``None``.  Never initializes a backend itself."""
+    import sys
+
+    if "jax" not in sys.modules:
+        return None
+    import jax
+    from jax._src import xla_bridge
+
+    if not xla_bridge.backends_are_initialized():
+        return None
+    platform = jax.default_backend()
+    return None if platform == "cpu" else platform
+
+
 class ProcessReplica:
     """Router-side handle on one spawned replica process.
+
+    A chip belongs to one process at a time.  A parent that already
+    holds an accelerator backend would leave every spawned child unable
+    to reach the device (it fails or hangs at boot), so the constructor
+    refuses to spawn in that case.  Replicas pinned to devices of one
+    multi-chip host are not implemented.
 
     The interface the ``Router`` consumes (and chaos tests fake):
     ``poll_messages`` (non-blocking drain), ``send`` (raises on a
@@ -265,6 +287,14 @@ class ProcessReplica:
     """
 
     def __init__(self, index: int, config: ReplicaConfig):
+        platform = held_accelerator()
+        if platform is not None:
+            raise RuntimeError(
+                f"refusing to spawn replica {index}: this process already "
+                f"holds the {platform} backend, and a device belongs to "
+                "one process at a time, so the child could not reach it. "
+                "Serve in one process (Frontend) on an accelerator host."
+            )
         ctx = multiprocessing.get_context("spawn")
         parent, child = ctx.Pipe()
         self.index = index

@@ -387,9 +387,25 @@ def test_shape_audit_catches_injected_lowering_disagreement():
 
 
 def test_vmem_model_passes_auto_selectable_widths():
-    from repro.analysis.shapes import check_width_gate, shape_vmem_audit
+    """Every gated width fits at a table the kernel can hold; at the
+    full-size dblp table (lane-padded, n_src = 2^20) none does — one
+    reason no auto path selects the Pallas lowering."""
+    from repro.analysis.shapes import (
+        CELL_N_SRC,
+        check_width_gate,
+        shape_vmem_audit,
+        vmem_footprint,
+    )
+    from repro.kernels.deliver import select_lowering
 
-    assert check_width_gate() == []
+    assert check_width_gate(n_src=4096) == []
+    full = check_width_gate()
+    assert full and all(f.rule == "vmem-budget" for f in full)
+    # D=1 fp32 pads to 128 lanes: the table alone is 512 MiB
+    table = vmem_footprint(block_n=128, block_e=256, d=1, itemsize=4,
+                           n_src=CELL_N_SRC, monoid_name="sum")
+    assert table["msgs_table"] >= 512 * 2**20
+    assert select_lowering() == "ell"
     assert shape_vmem_audit() == []
 
 
@@ -409,7 +425,7 @@ def test_vmem_model_rejects_wide_rows_at_worst_geometry():
     assert bad and bad[0].rule == "vmem-budget"
     assert "16 MiB" in bad[0].message
     # a hypothetical wider auto gate would be caught by the gate check
-    assert check_width_gate(width_budget_bytes=256.0) != []
+    assert check_width_gate(width_budget_bytes=256.0, n_src=4096) != []
 
 
 # --------------------------------------------------------------------------

@@ -439,3 +439,14 @@ def test_three_backends_agree_subprocess():
     )
     assert proc.returncode == 0, proc.stderr[-3000:]
     assert "BACKENDS_AGREE" in proc.stdout
+
+
+def test_make_host_mesh_refuses_more_devices_than_exist():
+    import jax
+
+    from repro.launch.mesh import make_host_mesh
+
+    n = len(jax.devices())
+    assert make_host_mesh(n).devices.size == n
+    with pytest.raises(RuntimeError, match="needs"):
+        make_host_mesh(n + 1)

@@ -596,6 +596,48 @@ def test_layout_fault_degrades_fused_to_xla():
     assert got.decision.get("degraded_from") == "pallas_fused"
 
 
+@pytest.mark.parametrize("disk", [False, True])
+def test_compile_error_propagates_without_degrading(tmp_path, monkeypatch,
+                                                    disk):
+    """A lowering or compile error (Mosaic, XLA) fails the request: it is
+    never served by the xla twin, and never by a plain-jit fallback."""
+    from repro.algorithms import shortest_paths_spec
+    from repro.core.serving import AotExecutable
+
+    def refuse(self, args):
+        raise RuntimeError("Mosaic refused the kernel")
+
+    monkeypatch.setattr(AotExecutable, "_materialize", refuse)
+    hg = powerlaw_hypergraph(47, 33, mean_cardinality=4, seed=0)
+    eng = Engine(disk_cache=DiskExecutableCache(tmp_path) if disk else None)
+    comp = eng.compile(shortest_paths_spec(hg, 0, 12),
+                       delivery="pallas_fused")
+    degraded0 = eng.metrics.counter("faults.delivery_degraded").value
+    with pytest.raises(RuntimeError, match="Mosaic refused"):
+        comp.run(query=3)
+    with pytest.raises(RuntimeError, match="Mosaic refused"):
+        comp.run_batch(np.asarray([1, 2], np.int32))
+    assert eng.metrics.counter(
+        "faults.delivery_degraded").value == degraded0
+    assert eng.cache_stats()["sources"] == {}
+    if disk:
+        assert eng.disk_cache.stats()["disk_stores"] == 0
+
+
+def test_injected_aot_compile_fault_propagates(tmp_path):
+    from repro.algorithms import shortest_paths_spec
+
+    inj = FaultInjector(FaultPlan((
+        FaultRule(point="compile.aot", trigger="always", error="fatal"),
+    )))
+    eng = Engine(disk_cache=DiskExecutableCache(tmp_path),
+                 fault_injector=inj)
+    hg = powerlaw_hypergraph(47, 33, mean_cardinality=4, seed=0)
+    with pytest.raises(InjectedFault):
+        eng.compile(shortest_paths_spec(hg, 0, 12)).run(query=1)
+    assert eng.cache_stats()["sources"] == {}
+
+
 # --------------------------------------------------------------------------
 # checkpoint/resume: chunked == uninterrupted, bitwise
 # --------------------------------------------------------------------------

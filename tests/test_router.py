@@ -566,3 +566,24 @@ def test_concurrent_warm_on_one_empty_store(tmp_path):
 
     cache = DiskExecutableCache(cache_dir)
     assert cache.stats()["entries"] >= 1
+
+
+def test_process_replica_refuses_to_spawn_on_a_held_accelerator(monkeypatch):
+    """A chip belongs to one process: a parent holding the TPU backend
+    must fail fast instead of spawning children that cannot reach it."""
+    from repro.serve import replica
+
+    monkeypatch.setattr(replica, "held_accelerator", lambda: "tpu")
+    cfg = replica.ReplicaConfig(builder="repro.launch.serve_hypergraph:"
+                                        "build_paths")
+    with pytest.raises(RuntimeError, match="one process at a time"):
+        replica.ProcessReplica(0, cfg)
+
+
+def test_held_accelerator_is_none_on_cpu():
+    import jax
+
+    from repro.serve.replica import held_accelerator
+
+    jax.devices()
+    assert held_accelerator() is None

@@ -349,6 +349,57 @@ def test_disk_cache_respects_env_dir(tmp_path, monkeypatch):
     assert str(DiskExecutableCache().root) == ".repro_cache"
 
 
+@pytest.mark.parametrize("disk", [False, True])
+def test_unlowerable_args_raise_instead_of_serving_through_jit(tmp_path,
+                                                               disk):
+    """Arguments AOT lowering cannot take fail loudly: no executable is
+    ever served through a plain-jit fallback."""
+    import jax
+
+    eng = Engine(disk_cache=DiskExecutableCache(tmp_path) if disk else None)
+    exe = eng._executable_for(("exotic",), lambda: jax.jit(lambda x, o: x))
+    with pytest.raises(TypeError):
+        exe(np.int32(1), object())
+    assert exe.source is None
+    assert eng.cache_stats()["sources"] == {}
+    if disk:
+        assert eng.disk_cache.stats()["entries"] == 0
+
+
+def test_executables_compile_ahead_of_first_call():
+    from repro.algorithms import shortest_paths_spec
+
+    hg = powerlaw_hypergraph(47, 33, mean_cardinality=4, seed=0)
+    eng = Engine()
+    report = eng.compile(shortest_paths_spec(hg, 0, 6)).warmup(
+        batch_sizes=(8,)
+    )
+    assert {p["source"] for p in report.values()} == {"aot"}
+    assert eng.cache_stats()["sources"] == {"aot": 2}
+
+
+def test_compile_cache_dir_from_env_or_fixed_repo_path(monkeypatch,
+                                                       tmp_path):
+    import jax
+
+    from repro.launch.compile_cache import repo_root, use_compile_cache
+
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        jax.config.update("jax_compilation_cache_dir", prev)
+        assert use_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == prev  # untouched
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        path = use_compile_cache()
+        assert path == str(repo_root() / ".jax_cache")
+        assert (repo_root() / "pyproject.toml").exists()
+        assert jax.config.jax_compilation_cache_dir == path
+        assert use_compile_cache() == path                  # stable
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
+
+
 def test_disk_cache_corrupt_blob_degrades_to_miss(tmp_path):
     cache = DiskExecutableCache(tmp_path)
     key = ("k",)
