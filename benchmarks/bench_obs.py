@@ -1,24 +1,27 @@
 """Observability tier: trace coverage + the zero-overhead contract.
 
-Two claims the obs tentpole makes about itself, measured:
+Claims the obs layer makes about itself, measured:
 
 * **trace coverage** — a traced end-to-end serve run (Engine(tracer=)
-  + Frontend, synchronous ``pump`` mode) exports valid Chrome-trace
-  JSON whose top-level (depth-0) spans account for the serve wall time
-  within 20%.  A tracer that drops the compile or misattributes the
-  execute would show up here as a coverage hole.
+  + Frontend, synchronous ``pump`` mode) under the JAX profiler puts
+  every span name on the profiler trace's host plane, and its
+  top-level (depth-0) spans account for the serve wall time within
+  20%.  A tracer that drops the compile or misattributes the execute
+  would show up here as a coverage hole.
 * **zero overhead untraced** — steady-state ``run_batch`` through an
   Engine WITHOUT a tracer must cost the same as one WITH a tracer to
   within noise (interleaved rounds, median of per-round ratios — the
-  ``bench_delivery`` discipline for this drifting shared host).  The
-  hot paths branch on ``tracer is None``; this is the canary that a
-  future edit doesn't move span bookkeeping onto the untraced path.
+  ``bench_delivery`` discipline for this drifting shared host).  With
+  no tracer and no profiler session a span site is one
+  ``TraceAnnotation.is_enabled()`` check, timed here per call; this is
+  the canary that a future edit doesn't move span bookkeeping onto
+  the untraced path.
 
 Writes ``BENCH_obs.json`` (uploaded by the nightly CI job).
 """
 from __future__ import annotations
 
-import json
+import glob
 import os
 import tempfile
 import time
@@ -29,7 +32,8 @@ import numpy as np
 from repro.algorithms import random_walk_spec, shortest_paths_spec
 from repro.core import Engine
 from repro.data import make_dataset
-from repro.obs import Tracer
+from repro.obs import Tracer, maybe_span
+from repro.obs.trace import COMPILE_SPANS
 from repro.serve import Frontend
 
 from benchmarks.common import SCALE, emit_json, row
@@ -61,12 +65,14 @@ def _traced_serve(hg) -> dict:
          int(rng.integers(0, hg.n_vertices)))
         for _ in range(REQUESTS)
     ]
-    t0 = time.perf_counter()
-    futs = [fe.submit(key, query=q) for key, q in trace]
-    fe.pump(drain=True)
-    for f in futs:
-        f.result()
-    wall_s = time.perf_counter() - t0
+    log_dir = tempfile.mkdtemp(prefix="repro-bench-obs-")
+    with jax.profiler.trace(log_dir):
+        t0 = time.perf_counter()
+        futs = [fe.submit(key, query=q) for key, q in trace]
+        fe.pump(drain=True)
+        for f in futs:
+            f.result()
+        wall_s = time.perf_counter() - t0
 
     spans = tracer.spans()
     top_s = sum(sp.dur_s for sp in spans if sp.depth == 0)
@@ -75,19 +81,15 @@ def _traced_serve(hg) -> dict:
     for sp in spans:
         by_cat[sp.cat] = by_cat.get(sp.cat, 0) + 1
 
-    # exported artifact must be loadable Chrome-trace JSON.
-    path = os.path.join(
-        tempfile.mkdtemp(prefix="repro-bench-obs-"), "serve.trace.json"
+    # every span the tracer opened is a host event of its name in the
+    # profiler's trace (JAX's own compile spans are recorded after the
+    # fact, and the profiler has its own events for them)
+    host = _host_event_names(log_dir)
+    opened = {sp.name for sp in spans} - set(COMPILE_SPANS.values())
+    missing = opened - host
+    assert opened and not missing, (
+        f"spans missing from the profiler trace's host plane: {missing}"
     )
-    tracer.export(path)
-    with open(path) as f:
-        doc = json.load(f)
-    events = doc["traceEvents"]
-    assert events, "traced serve run exported no events"
-    for ev in events:
-        for field in ("name", "cat", "ph", "ts", "dur", "pid", "tid"):
-            assert field in ev, f"Chrome-trace event missing {field}: {ev}"
-        assert ev["ph"] == "X", ev
 
     assert abs(coverage - 1.0) <= COVERAGE_BAND, (
         f"depth-0 span coverage {coverage:.2f} of serve wall "
@@ -103,8 +105,33 @@ def _traced_serve(hg) -> dict:
         "n_spans": len(spans),
         "dropped": tracer.dropped,
         "spans_by_cat": by_cat,
-        "trace_events": len(events),
+        "host_events": len(host),
     }
+
+
+def _host_event_names(log_dir: str) -> set:
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(
+        os.path.join(log_dir, "plugins", "profile", "*", "*.xplane.pb")
+    )
+    return {
+        ev.name
+        for plane in ProfileData.from_file(path).planes
+        if plane.name.startswith("/host:")
+        for line in plane.lines
+        for ev in line.events
+    }
+
+
+def _off_cost_ns(n: int = 200_000) -> float:
+    """Nanoseconds per ``maybe_span(None, ...)`` with no profiler
+    session: the cost an untraced span site pays."""
+    t0 = time.perf_counter()
+    for _ in range(n):
+        with maybe_span(None, "engine.run"):
+            pass
+    return (time.perf_counter() - t0) / n * 1e9
 
 
 def _overhead(hg) -> dict:
@@ -154,6 +181,7 @@ def run() -> None:
         "requests": REQUESTS,
         "traced_serve": _traced_serve(hg),
         "overhead": _overhead(hg),
+        "span_site_off_ns": _off_cost_ns(),
     }
     emit_json("obs", results)
 

@@ -634,9 +634,11 @@ class Engine:
         # Observability (repro.obs): an optional span recorder
         # (duck-typed like disk_cache: anything with span/block) and
         # the unified metrics registry this Engine's executable-cache
-        # counters surface through.  Both cost NOTHING on hot paths
-        # when unused: span sites branch on ``tracer is None`` and the
-        # registry provider is a weakref pulled only at snapshot time.
+        # counters surface through.  Span sites go through
+        # ``maybe_span``: with no tracer attached they record into
+        # ``default_tracer()`` while the JAX profiler records and cost
+        # one check otherwise; the registry provider is a weakref
+        # pulled only at snapshot time.
         self.tracer = tracer
         self.metrics = metrics if metrics is not None else default_registry()
         self.metrics.register_provider(
@@ -1164,18 +1166,24 @@ class Engine:
 
         ``overrides`` are per-call ``ExecutionConfig`` replacements
         (e.g. ``engine.run(spec, max_iters=8)``).
+
+        Spans: ``engine.run`` (resolve to value ready) over
+        ``engine.resolve``, ``engine.layout_build``, ``engine.dispatch``
+        (JAX's ``jax.trace`` / ``jax.lower`` / ``jax.compile`` inside)
+        and ``engine.device_wait``.
         """
-        resolved, plan, decision = self.resolve(spec, **overrides)
-        name = getattr(spec, "name", "anonymous")
+        with maybe_span(self.tracer, "engine.run", cat="execute",
+                        algorithm=getattr(spec, "name", "anonymous")):
+            return self._run(spec, overrides)
+
+    def _run(self, spec, overrides: dict) -> Result:
+        with maybe_span(self.tracer, "engine.resolve", cat="resolve"):
+            resolved, plan, decision = self.resolve(spec, **overrides)
 
         if resolved.representation == "clique":
             t0 = time.perf_counter()
-            with maybe_span(
-                self.tracer, "engine.run", cat="execute",
-                algorithm=name, representation="clique",
-            ):
-                graph = to_graph(spec.hg0)
-                value = spec.clique_program(graph)
+            graph = to_graph(spec.hg0)
+            value = spec.clique_program(graph)
             decision = {**decision, "measured": {
                 "wall_s": time.perf_counter() - t0,
             }}
@@ -1195,11 +1203,7 @@ class Engine:
                 else None
             )
             t0 = time.perf_counter()
-            with maybe_span(
-                self.tracer, "engine.run", cat="execute",
-                algorithm=name, backend="local",
-                delivery=resolved.delivery,
-            ) as sp:
+            with maybe_span(self.tracer, "engine.dispatch", cat="execute"):
                 if resolved.checkpoint_every is not None:
                     from repro.faults.checkpoint import checkpointed_compute
 
@@ -1228,11 +1232,11 @@ class Engine:
                         return_stats=resolved.collect_stats,
                         delivery=delivery,
                     )
-                t1 = time.perf_counter()
+            t1 = time.perf_counter()
+            with maybe_span(self.tracer, "engine.device_wait",
+                            cat="execute"):
                 jax.block_until_ready(out)
-                t2 = time.perf_counter()
-                if sp is not None:
-                    sp.args["device_wait_s"] = t2 - t1
+            t2 = time.perf_counter()
             stats = None
             if resolved.collect_stats:
                 out, stats = out
@@ -1251,11 +1255,7 @@ class Engine:
         from repro.core.distributed import distributed_compute
 
         t0 = time.perf_counter()
-        with maybe_span(
-            self.tracer, "engine.run", cat="execute",
-            algorithm=name, backend=resolved.backend,
-            delivery=resolved.delivery, n_parts=plan.n_parts,
-        ) as sp:
+        with maybe_span(self.tracer, "engine.dispatch", cat="execute"):
             if resolved.checkpoint_every is not None:
                 from repro.faults.checkpoint import (
                     checkpointed_distributed_compute,
@@ -1293,11 +1293,10 @@ class Engine:
                     return_stats=resolved.collect_stats,
                     delivery=resolved.delivery,
                 )
-            t1 = time.perf_counter()
+        t1 = time.perf_counter()
+        with maybe_span(self.tracer, "engine.device_wait", cat="execute"):
             jax.block_until_ready(out)
-            t2 = time.perf_counter()
-            if sp is not None:
-                sp.args["device_wait_s"] = t2 - t1
+        t2 = time.perf_counter()
         stats = None
         if resolved.collect_stats:
             out, stats = out
@@ -1460,18 +1459,16 @@ class Engine:
             self._cache_hits += 1
             return cache[key]
         self._cache_misses += 1
-        if self.tracer is None:
+        span_args = {
+            k: v
+            for k, v in (meta or {}).items()
+            if isinstance(v, (str, int, float, bool))
+        }
+        with maybe_span(
+            self.tracer, "engine.build_executable", cat="compile",
+            **span_args,
+        ):
             exe = build()
-        else:
-            span_args = {
-                k: v
-                for k, v in (meta or {}).items()
-                if isinstance(v, (str, int, float, bool))
-            }
-            with self.tracer.span(
-                "engine.build_executable", cat="compile", **span_args
-            ):
-                exe = build()
         if self.disk_cache is not None:
             exe = self.disk_cache.wrap(self, key, exe)
         else:

@@ -38,6 +38,7 @@ from repro.core.engine import compute, compute_batch
 from repro.core.hypergraph import HyperGraph
 from repro.faults.errors import is_transient
 from repro.kernels.deliver import layout_pair
+from repro.obs.trace import maybe_span
 
 Pytree = Any
 
@@ -578,15 +579,12 @@ class CompiledAlgorithm:
         metrics = getattr(engine, "metrics", None)
         if metrics is not None:
             metrics.counter("faults.delivery_degraded").inc()
-        tracer = getattr(engine, "tracer", None)
-        if tracer is not None:
-            from repro.obs.trace import maybe_span
-
-            with maybe_span(
-                tracer, "faults.degrade_delivery", cat="faults",
-                algorithm=self.spec.name, error=type(err).__name__,
-            ):
-                pass
+        with maybe_span(
+            getattr(engine, "tracer", None), "faults.degrade_delivery",
+            cat="faults", algorithm=self.spec.name,
+            error=type(err).__name__,
+        ):
+            pass
         return self._xla_twin
 
     def _run_checkpointed(self, hg, query):
@@ -723,8 +721,6 @@ class CompiledAlgorithm:
         delivery = None
         delivery_sig = None
         if cfg.delivery == "pallas_fused":
-            from repro.obs.trace import maybe_span
-
             with maybe_span(
                 self.engine.tracer, "serve.layout_build", cat="compile",
                 algorithm=self.spec.name, nnz_pad=int(nnz_pad),
@@ -864,15 +860,20 @@ class CompiledAlgorithm:
                 batch=int(b) if b is not None else 0,
             )
 
-        # Tracing on the serve hot path is strictly opt-in: without a
-        # tracer this is exactly ``exe(*args)`` — no timing, no
-        # allocation (the zero-overhead contract bench_obs asserts).
+        # Blocking and measured timing on the serve hot path are
+        # strictly opt-in: without an attached tracer this is
+        # ``exe(*args)`` under ``maybe_span`` (a span while the JAX
+        # profiler records, one check otherwise) — the zero-overhead
+        # contract bench_obs asserts.
         tracer = engine.tracer
         timing: dict = {}
 
         def _call():
             if tracer is None:
-                return exe(*args)
+                with maybe_span(None, "engine.execute", cat="execute",
+                                algorithm=spec.name):
+                    out = exe(*args)
+                return out
             t0 = time.perf_counter()
             traces0 = engine._trace_count
             with tracer.span(

@@ -70,9 +70,10 @@ def _parse(argv=None):
     ap.add_argument("--explain", action="store_true",
                     help="print the full auto-axis decision tree "
                          "(per-candidate predicted costs) before running")
-    ap.add_argument("--trace", default=None, metavar="PATH",
-                    help="record engine trace spans; export Chrome-trace "
-                         "JSON here (loadable in Perfetto)")
+    ap.add_argument("--trace", default=None, metavar="DIR",
+                    help="run under the JAX profiler, writing its trace "
+                         "(engine spans and device ops on one clock; "
+                         "TensorBoard / XProf) under DIR")
     ap.add_argument("--metrics-json", default=None, metavar="PATH",
                     help="write the unified metrics-registry snapshot "
                          "as JSON ('-' for stdout)")
@@ -131,10 +132,6 @@ def _print_explain(ex: dict) -> None:
 
 
 def _emit_obs(engine, args) -> None:
-    if args.trace and engine.tracer is not None:
-        engine.tracer.export(args.trace)
-        print(f"trace: {len(engine.tracer.spans())} spans "
-              f"({engine.tracer.dropped} dropped) -> {args.trace}")
     if args.metrics_json:
         import json
 
@@ -156,6 +153,18 @@ def main(argv=None) -> int:
             + os.environ.get("XLA_FLAGS", "")
         )
 
+    from repro.obs.trace import default_tracer, profile
+
+    with profile(args.trace):
+        rc = _run(args)
+    if args.trace:
+        tr = default_tracer()
+        print(f"trace: {len(tr.spans())} program spans "
+              f"({tr.dropped} dropped) -> {args.trace}")
+    return rc
+
+
+def _run(args) -> int:
     import jax
     import numpy as np
 
@@ -172,14 +181,8 @@ def main(argv=None) -> int:
           f"nnz={hg.nnz}")
 
     mesh = make_host_mesh(args.devices) if args.devices > 1 else None
-    tracer = None
-    if args.trace:
-        from repro.obs import Tracer
-
-        tracer = Tracer()
     engine = Engine(
         mesh=mesh,
-        tracer=tracer,
         representation=args.representation,
         backend=args.backend,
         partition_strategy=args.partition,

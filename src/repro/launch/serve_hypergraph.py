@@ -95,9 +95,10 @@ def _parse(argv=None):
                          "from the observed wait/execute split "
                          "(bounded EWMA controller; --max-delay-ms "
                          "becomes the upper clamp)")
-    ap.add_argument("--trace", default=None, metavar="PATH",
-                    help="record engine + serve trace spans; export "
-                         "Chrome-trace JSON here (loadable in Perfetto)")
+    ap.add_argument("--trace", default=None, metavar="DIR",
+                    help="run under the JAX profiler, writing its trace "
+                         "(engine + serve spans and device ops on one "
+                         "clock; TensorBoard / XProf) under DIR")
     ap.add_argument("--metrics-json", default=None, metavar="PATH",
                     help="write the unified metrics-registry snapshot "
                          "as JSON ('-' for stdout)")
@@ -138,6 +139,18 @@ def main(argv=None) -> int:
             + os.environ.get("XLA_FLAGS", "")
         )
 
+    from repro.obs.trace import default_tracer, profile
+
+    with profile(args.trace):
+        rc = _run(args)
+    if args.trace:
+        tr = default_tracer()
+        print(f"trace: {len(tr.spans())} program spans "
+              f"({tr.dropped} dropped) -> {args.trace}")
+    return rc
+
+
+def _run(args) -> int:
     import jax
     import numpy as np
 
@@ -155,11 +168,6 @@ def main(argv=None) -> int:
           f"nnz={hg.nnz}")
 
     mesh = make_host_mesh(args.devices) if args.devices > 1 else None
-    tracer = None
-    if args.trace:
-        from repro.obs import Tracer
-
-        tracer = Tracer()
     injector, plan_json = None, None
     if args.fault_plan:
         from repro.faults import FaultInjector, FaultPlan
@@ -176,7 +184,6 @@ def main(argv=None) -> int:
         print(f"fault-plan: {len(plan.rules)} rule(s) armed")
     engine = Engine(
         mesh=mesh, disk_cache=DiskExecutableCache(args.cache_dir),
-        tracer=tracer,
         # In pool mode the parent engine is the prewarmer + verify
         # oracle, never the system under test: the plan is armed inside
         # each replica (and on the router for ``router.route``) instead.
@@ -285,10 +292,6 @@ def main(argv=None) -> int:
 
     if args.json:
         print(json.dumps(st, indent=2, sort_keys=True, default=str))
-    if args.trace and tracer is not None:
-        tracer.export(args.trace)
-        print(f"trace: {len(tracer.spans())} spans "
-              f"({tracer.dropped} dropped) -> {args.trace}")
     if args.metrics_json:
         payload = json.dumps(engine.metrics.snapshot(), indent=2,
                              sort_keys=True, default=str)

@@ -3,10 +3,12 @@
 Three cross-cutting pieces, wired through core and serve:
 
 * ``trace``     — clock-injected, ring-buffered ``Tracer`` recording
-  phase spans (layout build, compile, disk load, execute, serve flush)
-  with device time from ``block_until_ready`` deltas; exports
-  Chrome-trace JSON loadable in Perfetto.  Attach with
-  ``Engine(tracer=Tracer())``; zero overhead when absent.
+  phase spans (resolve, layout build, dispatch with JAX's trace / lower
+  / compile inside, device wait, disk load, serve flush), each also a
+  profiler ``TraceAnnotation`` on the device trace's clock.  Attach
+  with ``Engine(tracer=Tracer())``; with none attached, spans go to
+  ``default_tracer()`` while the JAX profiler records, and cost one
+  check otherwise.
 * ``metrics``   — the unified ``MetricsRegistry`` (counters, gauges,
   log-spaced histograms, snapshot providers) every counting subsystem
   registers into; one ``snapshot()``, surfaced via ``--metrics-json``
@@ -36,7 +38,7 @@ from repro.obs.metrics import (
     reset_default_registry,
     weak_provider,
 )
-from repro.obs.trace import Span, Tracer, maybe_span
+from repro.obs.trace import Span, Tracer, default_tracer, maybe_span
 
 __all__ = [
     "Counter",
@@ -47,6 +49,7 @@ __all__ = [
     "Tracer",
     "decision_residuals",
     "default_registry",
+    "default_tracer",
     "delivery_calibration",
     "delivery_traffic_pair",
     "executed_supersteps",

@@ -571,11 +571,12 @@ class Frontend:
                         )
                     res = path.compiled.run_batch(queries, hg=flush.hg)
                     value = res.value
-                    if sp is not None:
+                    if tracer is not None:
                         tracer.block(sp, value)
-                        sp.args["max_wait_s"] = max(waits, default=0.0)
                     else:
                         _block(value)
+                    if sp is not None:
+                        sp.args["max_wait_s"] = max(waits, default=0.0)
                 return res, value, self.clock() - dispatch
             except Exception as err:
                 if (

@@ -2,9 +2,13 @@
 
 The tentpole contracts, asserted:
 
-* ``Tracer`` spans nest per thread, the ring keeps the newest spans
-  (counting the dropped rest), and the export is schema-valid
-  Chrome-trace JSON (``ph: "X"`` complete events, microsecond fields);
+* ``Tracer`` spans nest per thread with ids and parent ids, the ring
+  keeps the newest spans (counting the dropped rest), and every span is
+  a host event of its name in the JAX profiler's trace;
+* with no tracer attached, ``Engine.run`` records its span tree
+  (resolve, layout build, dispatch with JAX's compile spans inside,
+  device wait) into ``default_tracer()`` while the profiler records,
+  and nothing otherwise;
 * ``MetricsRegistry`` get-or-creates typed instruments, suffixes
   colliding provider names, prunes dead weakref providers, and keeps
   snapshotting through a provider that throws;
@@ -20,10 +24,12 @@ The tentpole contracts, asserted:
   and warns on host-dependent drift.
 """
 import gc
+import glob
 import importlib.util
 import json
 import os
 
+import jax
 import numpy as np
 import pytest
 from hypothesis import given, settings
@@ -37,6 +43,7 @@ from repro.obs import (
     MetricsRegistry,
     Tracer,
     decision_residuals,
+    default_tracer,
     delivery_calibration,
     executed_supersteps,
     fused_traffic,
@@ -57,7 +64,7 @@ class FakeClock:
 
 
 # --------------------------------------------------------------------------
-# Tracer: nesting, ring eviction, Chrome-trace schema
+# Tracer: nesting, ring eviction, ids, the profiler's trace
 # --------------------------------------------------------------------------
 
 def test_span_nesting_and_durations_fake_clock():
@@ -95,32 +102,95 @@ def test_ring_eviction_keeps_newest_and_counts_dropped():
     assert tr.spans() == [] and tr.dropped == 0
 
 
-def test_chrome_trace_schema_and_export(tmp_path):
-    clock = FakeClock()
-    tr = Tracer(clock=clock)
-    with tr.span("compile", cat="compile", key="k"):
-        clock.t = 0.5
-        with tr.span("execute", cat="execute"):
-            clock.t = 0.75
-    doc = tr.chrome_trace()
-    assert doc["otherData"]["dropped_spans"] == 0
-    events = doc["traceEvents"]
-    assert len(events) == 2
-    for ev in events:
-        for field in ("name", "cat", "ph", "ts", "dur", "pid", "tid"):
-            assert field in ev
-        assert ev["ph"] == "X"
-        assert ev["dur"] >= 0.0
-        assert "depth" in ev["args"]
-    path = tr.export(str(tmp_path / "trace.json"))
-    with open(path) as f:
-        reloaded = json.load(f)
-    assert reloaded["traceEvents"] == json.loads(json.dumps(events))
+def _profiled(log_dir):
+    """A CPU profiler session without the Python tracer (fast)."""
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    return jax.profiler.trace(str(log_dir), profiler_options=opts)
+
+
+def _host_event_names(log_dir) -> set:
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(
+        os.path.join(str(log_dir), "plugins", "profile", "*", "*.xplane.pb")
+    )
+    return {
+        ev.name
+        for plane in ProfileData.from_file(path).planes
+        if plane.name.startswith("/host:")
+        for line in plane.lines
+        for ev in line.events
+    }
+
+
+def test_tracer_span_is_a_host_event_in_the_profiler_trace(tmp_path):
+    tr = Tracer()
+    with _profiled(tmp_path):
+        with tr.span("engine.phase_a", cat="execute"):
+            with tr.span("engine.phase_b"):
+                pass
+    names = _host_event_names(tmp_path)
+    assert {"engine.phase_a", "engine.phase_b"} <= names
+    assert [s.name for s in tr.spans()] == [
+        "engine.phase_b", "engine.phase_a"
+    ]
+
+
+def test_span_ids_and_parents():
+    tr = Tracer(clock=FakeClock())
+    with tr.span("outer") as outer:
+        with tr.span("mid") as mid:
+            with tr.span("inner") as inner:
+                pass
+        with tr.span("sibling") as sib:
+            pass
+    assert len({outer.id, mid.id, inner.id, sib.id}) == 4
+    assert outer.parent is None
+    assert mid.parent == outer.id and sib.parent == outer.id
+    assert inner.parent == mid.id
+    with tr.span("next") as nxt:
+        pass
+    assert nxt.parent is None and nxt.id > sib.id
 
 
 def test_maybe_span_is_noop_without_tracer():
+    before = default_tracer().total
     with maybe_span(None, "anything", cat="execute", k=2) as sp:
         assert sp is None
+    assert default_tracer().total == before
+
+
+def test_maybe_span_records_into_default_tracer_while_profiling(tmp_path):
+    default_tracer().clear()
+    with _profiled(tmp_path):
+        with maybe_span(None, "engine.probe", cat="execute", k=2) as sp:
+            assert sp is not None
+    (got,) = default_tracer().spans()
+    assert got is sp and got.args == {"k": 2}
+    assert "engine.probe" in _host_event_names(tmp_path)
+    default_tracer().clear()
+
+
+def test_compile_spans_join_the_open_span_of_an_attached_tracer():
+    tr = Tracer()
+    fresh = jax.jit(lambda x: x * 3.0 + 1.0)
+    with tr.span("outer") as outer:
+        jax.block_until_ready(fresh(np.arange(7, dtype=np.float32)))
+    compiled = [s for s in tr.spans() if s.name.startswith("jax.")]
+    names = {s.name for s in compiled}
+    assert {"jax.trace", "jax.lower", "jax.compile"} <= names
+    for s in compiled:
+        assert s.parent == outer.id and s.cat == "compile"
+        assert "fun_name" in s.args
+        assert outer.t0 - 1e-3 <= s.t0
+        assert s.t0 + s.dur_s <= outer.t0 + outer.dur_s + 1e-3
+    # no span open: a compile records nowhere
+    total = tr.total
+    jax.block_until_ready(
+        jax.jit(lambda x: x - 2.0)(np.arange(5, dtype=np.float32))
+    )
+    assert tr.total == total
 
 
 def test_tracer_block_records_device_wait():
@@ -483,6 +553,91 @@ def test_traced_compile_and_serve_records_phases(hg):
     res = compiled.run_batch(np.asarray([3, 4], np.int32))
     assert "measured" in res.decision
     assert res.decision["measured"]["wall_s"] > 0
+
+
+MEASURED_KEYS = {"wall_s", "dispatch_s", "device_wait_s", "max_iters"}
+
+
+def test_profiled_run_records_the_engine_span_tree(hg, tmp_path):
+    """No tracer attached: while the profiler records, ``Engine.run``
+    records resolve / layout build / dispatch (JAX's trace, lower and
+    compile inside) / device wait under ``engine.run``."""
+    default_tracer().clear()
+    eng = Engine(delivery="pallas_fused")
+    with _profiled(tmp_path):
+        res = eng.run(alg.pagerank_spec(hg, iters=3))
+    spans = default_tracer().spans()
+    assert default_tracer().dropped == 0
+    by_id = {s.id: s for s in spans}
+    (run,) = [s for s in spans if s.name == "engine.run"]
+    assert run.parent is None and run.args == {"algorithm": "pagerank"}
+    children = sorted(
+        (s for s in spans if s.parent == run.id), key=lambda s: s.t0
+    )
+    assert [s.name for s in children] == [
+        "engine.resolve", "engine.layout_build", "engine.dispatch",
+        "engine.device_wait",
+    ]
+    for c in children:
+        assert run.t0 <= c.t0
+        assert c.t0 + c.dur_s <= run.t0 + run.dur_s
+    dispatch = children[2]
+    under = [s for s in spans if s.parent == dispatch.id]
+    assert any(s.name == "jax.compile" for s in under)
+    assert {s.name for s in under} <= {"jax.trace", "jax.lower",
+                                       "jax.compile"}
+    for s in spans:
+        assert s.parent is None or s.parent in by_id
+    # the measured decision keeps its keys and its device wait
+    m = res.decision["measured"]
+    assert MEASURED_KEYS | {"delivery"} == set(m)
+    assert m["device_wait_s"] <= children[3].dur_s + 1e-3
+    host = _host_event_names(tmp_path)
+    assert {c.name for c in children} | {"engine.run"} <= host
+    default_tracer().clear()
+
+
+def test_unprofiled_run_records_nothing(hg):
+    default_tracer().clear()
+    res = Engine().run(alg.pagerank_spec(hg, iters=3))
+    assert default_tracer().total == 0
+    assert set(res.decision["measured"]) == MEASURED_KEYS
+    res = Engine(collect_stats=True).run(alg.pagerank_spec(hg, iters=3))
+    assert default_tracer().total == 0
+    assert set(res.decision["measured"]) == MEASURED_KEYS | {"supersteps"}
+
+
+def test_attached_tracer_records_the_run_span_tree(hg):
+    tr = Tracer()
+    Engine(tracer=tr).run(alg.pagerank_spec(hg, iters=3))
+    names = [s.name for s in tr.spans()
+             if s.name.startswith("engine.")]
+    assert names[-1] == "engine.run"
+    assert {"engine.resolve", "engine.dispatch",
+            "engine.device_wait"} <= set(names)
+
+
+def test_profiled_serve_without_tracer_records_its_spans(hg, tmp_path):
+    """No tracer attached: the serve pump's flush and execute spans go
+    to ``default_tracer()`` while the profiler records, and the flush
+    blocks on its value without an attached tracer's timing."""
+    from repro.serve import Frontend
+
+    default_tracer().clear()
+    fe = Frontend(Engine(), max_batch=4, max_delay_ms=1.0)
+    fe.register("sssp", alg.shortest_paths_spec(hg, 0, 4))
+    with _profiled(tmp_path):
+        futs = [fe.submit("sssp", query=q) for q in (0, 1, 2)]
+        fe.pump(drain=True)
+        for f in futs:
+            f.result(timeout=60)
+    spans = default_tracer().spans()
+    by_id = {s.id: s for s in spans}
+    (flush,) = [s for s in spans if s.name == "serve.flush"]
+    (execute,) = [s for s in spans if s.name == "engine.execute"]
+    assert "device_wait_s" not in flush.args and "max_wait_s" in flush.args
+    assert by_id[execute.parent].name == "serve.flush"
+    default_tracer().clear()
 
 
 def test_untraced_serve_skips_measured_enrichment(hg):
