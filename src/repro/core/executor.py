@@ -46,9 +46,8 @@ from repro.obs.metrics import default_registry, weak_provider
 from repro.obs.trace import maybe_span
 from repro.kernels.deliver import (
     DELIVERY_MODES,
+    delivery_structure,
     layout_pair,
-    plan_degree_classes,
-    plan_ell_width,
     select_lowering,
 )
 
@@ -454,36 +453,28 @@ def message_width_bytes(initial_msg: Any) -> float:
     return max(total, 1.0)
 
 
-def select_delivery(spec, hg: HyperGraph) -> tuple[str, dict]:
-    """Fused vs reference delivery for one spec — the tentpole's cost
-    model over nnz, message width, dtype and degree skew.
+def select_delivery(
+    spec, hg: HyperGraph, structure: Callable[[], dict] | None = None
+) -> tuple[str, dict]:
+    """Fused vs reference delivery for one spec: a cost model over nnz,
+    message width and degree skew.
 
-    Hard gates first: the fused kernel folds the combine into the
-    layout, so custom ``reducer``s / ``edge_transform``s (which consume
-    materialized per-incidence rows) and empty structures take ``xla``.
+    Hard gates first: custom ``reducer``s / ``edge_transform``s consume
+    materialized per-incidence rows, so they and empty structures take
+    ``xla``.  ``why["lowering"]`` names the lowering the fused path
+    will run (``select_lowering``).  The padding term is the summed
+    work of both directions' degree-class plans at the local builder's
+    row padding (``delivery_structure``; the distributed builder
+    harmonizes pads to shard maxima, so there it is a lower bound).
+    Pick fused while (a) that work is within ``FUSED_ELL_WORK_BUDGET``
+    slots per incidence, both directions, and (b) the message row is
+    within ``FUSED_MAX_WIDTH_BYTES``.  ``skew_gain`` (single-ELL vs
+    class plan, residual-weighted) says how much of the decision the
+    degree classes carry.
 
-    Then one cost model on every platform; ``why["lowering"]`` names
-    the lowering the fused path will run
-    (``repro.kernels.deliver.select_lowering``: ``ell``, the XLA
-    sliced-ELL form, unless overridden).  The fused win comes from
-    replacing the serialized scatter with dense reduces, and dies by
-    padding.  The padding term is the degree-class plan's summed work
-    (``plan_degree_classes`` over both directions' live-degree
-    histograms — dense slots at the builder's pow2 row padding
-    (``ClassPlan.built_work``) plus residual; exactly what a layout
-    built by the LOCAL builder allocates, so model and builder cannot
-    disagree there.  The distributed builder plans from merged
-    per-shard histograms and harmonizes pads to shard maxima, so its
-    realized padded work can exceed this estimate on shard-skewed cuts
-    — the budget is a lower bound in that case).  Pick fused while
-    (a) class padding is bounded (``FUSED_ELL_WORK_BUDGET`` slots per
-    incidence, both directions) and (b) the message row is within
-    ``FUSED_MAX_WIDTH_BYTES`` — a boundary the class layout MOVED: at
-    64-byte rows under zipf skew the PR-4 single-ELL packing measured
-    a ~2x loss to the reference (overflow scatter), while per-class
-    widths keep hubs dense and win the regime.  The reported
-    ``skew_gain`` (single-ELL vs class plan, residual-weighted)
-    quantifies how much of the decision the degree classes carry.
+    ``structure``, when given, returns ``delivery_structure`` of ``hg``
+    (the Engine passes its per-structure cache); else it is computed
+    here.
     """
     reason = _non_monoid_reason(spec)
     why: dict[str, Any] = {}
@@ -495,14 +486,10 @@ def select_delivery(spec, hg: HyperGraph) -> tuple[str, dict]:
         return "xla", why
 
     why["lowering"] = select_lowering()
-    live = (
-        np.asarray(hg.e_mask) != 0
-        if hg.e_mask is not None
-        else np.ones(hg.nnz, bool)
+    inputs = structure() if structure is not None else delivery_structure(
+        hg.src, hg.dst, hg.e_mask, hg.n_vertices, hg.n_hyperedges
     )
-    src = np.asarray(hg.src)[live]
-    dst = np.asarray(hg.dst)[live]
-    nnz = int(live.sum())
+    nnz = inputs["nnz"]
     if nnz == 0:
         why["reason"] = "no live incidences"
         return "xla", why
@@ -515,46 +502,23 @@ def select_delivery(spec, hg: HyperGraph) -> tuple[str, dict]:
         )
         return "xla", why
 
-    from repro.kernels.deliver.layout import RESIDUAL_WEIGHT
-
-    class_work = 0.0
-    class_weighted = 0.0
-    single_weighted = 0.0
-    residual = 0
-    plans = {}
-    for side, n_dst, ids in (
-        ("fwd", hg.n_hyperedges, dst), ("bwd", hg.n_vertices, src)
-    ):
-        deg = np.bincount(ids, minlength=n_dst)
-        plan = plan_degree_classes(deg, nnz)
-        k1, rem1 = plan_ell_width(deg, nnz)
-        # built_work: dense slots at the builder's pow2 row padding —
-        # the work the layout will really do, not the DP's tight count.
-        class_work += float(plan.built_work)
-        class_weighted += float(
-            plan.built_work - plan.residual
-            + RESIDUAL_WEIGHT * plan.residual
-        )
-        single_weighted += float(n_dst * k1 + RESIDUAL_WEIGHT * rem1)
-        residual = max(residual, plan.residual)
-        plans[side] = {
-            "widths": plan.widths, "rows": plan.rows,
-            "residual": plan.residual,
-        }
+    class_work = inputs["class_work_slots"]
     # Residual lanes pay the serialized sorted segment reduce, dense
     # slots a vectorized reduce — compare plans on the weighted scale
     # the DP itself optimizes.
-    skew_gain = single_weighted / max(class_weighted, 1.0)
+    skew_gain = inputs["single_ell_weighted_work"] / max(
+        inputs["class_weighted_work"], 1.0
+    )
     why.update(
         nnz=nnz,
         class_work_slots=class_work,
-        class_weighted_work=class_weighted,
-        single_ell_weighted_work=single_weighted,
+        class_weighted_work=inputs["class_weighted_work"],
+        single_ell_weighted_work=inputs["single_ell_weighted_work"],
         skew_gain=skew_gain,
         work_budget=FUSED_ELL_WORK_BUDGET * 2 * nnz,
-        residual=residual,
+        residual=inputs["residual"],
         width_budget=FUSED_MAX_WIDTH_BYTES,
-        class_plans=plans,
+        class_plans={k: dict(p) for k, p in inputs["class_plans"].items()},
     )
     if class_work > FUSED_ELL_WORK_BUDGET * 2 * nnz:
         why["reason"] = "degree-class padding exceeds the work budget"
@@ -572,6 +536,41 @@ def select_delivery(spec, hg: HyperGraph) -> tuple[str, dict]:
            else "(bounded class padding)")
     )
     return "pallas_fused", why
+
+
+def _structure_key(hg: HyperGraph) -> tuple:
+    """The incidence itself when its arrays are immutable ``jax.Array``s,
+    so every wrapper (``with_attrs``, ``spec.init``) shares one entry;
+    else (numpy, mutable in place) the wrapper's identity."""
+    arrays = (hg.src, hg.dst) + (() if hg.e_mask is None else (hg.e_mask,))
+    if all(isinstance(a, jax.Array) for a in arrays):
+        return (hg.src, hg.dst, hg.e_mask, hg.n_vertices, hg.n_hyperedges)
+    return (hg,)
+
+
+def _same_structure(a: tuple, b: tuple) -> bool:
+    """Keys match: the same objects (``is``), and equal sizes."""
+    return (len(a) == len(b) and all(x is y for x, y in zip(a[:3], b[:3]))
+            and a[3:] == b[3:])
+
+
+@dataclasses.dataclass(eq=False)
+class _Structure:
+    """One structure's entry, filled on first use; ``hg`` without attrs."""
+
+    key: tuple
+    hg: HyperGraph
+    layouts: Any = None
+    plans: dict = dataclasses.field(default_factory=dict)
+    delivery: dict | None = None
+
+    def delivery_inputs(self) -> dict:
+        if self.delivery is None:
+            hg = self.hg
+            self.delivery = delivery_structure(
+                hg.src, hg.dst, hg.e_mask, hg.n_vertices, hg.n_hyperedges
+            )
+        return self.delivery
 
 
 class Engine:
@@ -607,13 +606,14 @@ class Engine:
         self.plan = plan
         self.mesh = mesh
         self.config = cfg
-        # Auto-built plans, keyed by hypergraph identity: repeated
-        # run()/resolve() on the same hypergraph must not re-run the
-        # full strategy sweep.  [(hg, n_parts, strategy, plan, why)]
-        self._plan_cache: list = []
-        # Fused-delivery layouts, keyed the same way: the dst-sort +
-        # ELL/CSR precompute is paid once per structure.  [(hg, layouts)]
-        self._delivery_cache: list = []
+        # What derives from the incidence alone (fused layouts,
+        # partition plans, the delivery cost model's inputs), paid once
+        # per structure: every spec wraps its hypergraph anew, so the
+        # key is the incidence, not the wrapper.  [_Structure], LRU.
+        self._structures: list = []
+        self._structure_hits = 0
+        self._structure_misses = 0
+        self._layout_builds = 0
         # Compile-once serve-many state: the LRU of shape-bucketed
         # executables behind Engine.compile / CompiledAlgorithm (keyed
         # by repro.core.serving.signature), plus the observability
@@ -653,6 +653,11 @@ class Engine:
             disk_cache.fault_injector = fault_injector
 
     # -- resolution ---------------------------------------------------------
+
+    def _config(self, overrides: Mapping[str, Any]) -> ExecutionConfig:
+        """This Engine's config with per-call ``overrides`` applied."""
+        return (dataclasses.replace(self.config, **overrides)
+                if overrides else self.config)
 
     def _resolve_representation(self, spec, cfg) -> tuple[str, dict]:
         if cfg.representation == "bipartite":
@@ -711,7 +716,9 @@ class Engine:
             spec, spec.hg0, edge_budget=cfg.clique_edge_budget
         )
 
-    def _resolve_backend(self, spec, cfg) -> tuple[str, Any, dict, dict]:
+    def _resolve_backend(
+        self, spec, cfg, entry
+    ) -> tuple[str, Any, dict, dict]:
         """Returns (backend, plan_or_None, backend_why, partition_why)."""
         if cfg.backend == "local":
             return "local", None, {"reason": "explicitly configured"}, {}
@@ -729,7 +736,7 @@ class Engine:
         part_why: dict[str, Any] = {}
         if plan is None:
             plan, part_why = self._cached_plan(
-                spec.hg0, n_parts, cfg.partition_strategy
+                spec.hg0, n_parts, cfg.partition_strategy, entry
             )
         else:
             part_why = {"strategy": plan.name,
@@ -758,16 +765,31 @@ class Engine:
         )
         return backend, plan, why, part_why
 
-    def _cached_plan(self, hg, n_parts: int, strategy: str):
-        for c_hg, c_parts, c_strat, c_plan, c_why in self._plan_cache:
-            if c_hg is hg and c_parts == n_parts and c_strat == strategy:
-                return c_plan, c_why
-        plan, why = select_partition(hg, n_parts, strategy)
-        self._plan_cache.append((hg, n_parts, strategy, plan, why))
-        del self._plan_cache[:-4]  # bound the strong refs we hold
-        return plan, why
+    def _structure(self, hg) -> tuple["_Structure", bool]:
+        """This structure's cache entry and whether it was there."""
+        key = _structure_key(hg)
+        for i, entry in enumerate(self._structures):
+            if _same_structure(entry.key, key):
+                self._structures.append(self._structures.pop(i))
+                self._structure_hits += 1
+                return entry, True
+        entry = _Structure(key, dataclasses.replace(
+            hg, v_attr=None, he_attr=None, e_attr=None
+        ))
+        self._structures.append(entry)
+        del self._structures[:-4]  # bound the strong refs we hold
+        self._structure_misses += 1
+        return entry, False
 
-    def _resolve_delivery(self, spec, cfg) -> tuple[str, dict]:
+    def _cached_plan(self, hg, n_parts: int, strategy: str, entry=None):
+        entry = entry if entry is not None else self._structure(hg)[0]
+        if (n_parts, strategy) not in entry.plans:
+            entry.plans[n_parts, strategy] = select_partition(
+                entry.hg, n_parts, strategy
+            )
+        return entry.plans[n_parts, strategy]
+
+    def _resolve_delivery(self, spec, cfg, entry) -> tuple[str, dict]:
         if cfg.delivery == "xla":
             return "xla", {"reason": "explicitly configured"}
         if cfg.delivery == "pallas_fused":
@@ -783,25 +805,24 @@ class Engine:
                     "delivery='pallas_fused' needs a non-empty incidence"
                 )
             return "pallas_fused", {"reason": "explicitly configured"}
-        return select_delivery(spec, spec.hg0)
+        return select_delivery(spec, spec.hg0, entry.delivery_inputs)
 
-    def _delivery_layouts(self, hg):
-        """Both directions' fused layouts for one structure, cached by
-        hypergraph identity (host-side dst-sort + ELL/CSR precompute)."""
-        for c_hg, lay in self._delivery_cache:
-            if c_hg is hg:
-                return lay
-        with maybe_span(
-            self.tracer, "engine.layout_build", cat="compile",
-            nnz=int(hg.nnz), n_vertices=int(hg.n_vertices),
-            n_hyperedges=int(hg.n_hyperedges),
-        ):
-            lay = layout_pair(
-                hg.src, hg.dst, hg.e_mask, hg.n_vertices, hg.n_hyperedges
-            )
-        self._delivery_cache.append((hg, lay))
-        del self._delivery_cache[:-4]  # bound the strong refs we hold
-        return lay
+    def _delivery_layouts(self, entry):
+        """Both directions' fused layouts for one structure (host-side
+        dst-sort + ELL/CSR precompute), built on its first use."""
+        if entry.layouts is None:
+            hg = entry.hg
+            with maybe_span(
+                self.tracer, "engine.layout_build", cat="compile",
+                nnz=int(hg.nnz), n_vertices=int(hg.n_vertices),
+                n_hyperedges=int(hg.n_hyperedges),
+            ):
+                entry.layouts = layout_pair(
+                    hg.src, hg.dst, hg.e_mask, hg.n_vertices,
+                    hg.n_hyperedges,
+                )
+            self._layout_builds += 1
+        return entry.layouts
 
     # -- execution ----------------------------------------------------------
 
@@ -815,11 +836,10 @@ class Engine:
         cheap decision tests (no compilation happens here; partition
         construction does run when a plan must be built).
         """
-        cfg = (
-            dataclasses.replace(self.config, **overrides)
-            if overrides
-            else self.config
-        )
+        return self._resolve(spec, overrides, self._structure(spec.hg0)[0])
+
+    def _resolve(self, spec, overrides: dict, entry):
+        cfg = self._config(overrides)
         decision: dict[str, Any] = {}
         representation, rep_why = self._resolve_representation(spec, cfg)
         decision["representation"] = rep_why
@@ -845,12 +865,12 @@ class Engine:
             return resolved, None, decision
 
         backend, plan, backend_why, part_why = self._resolve_backend(
-            spec, cfg
+            spec, cfg, entry
         )
         decision["backend"] = backend_why
         if part_why:
             decision["partition"] = part_why
-        delivery, delivery_why = self._resolve_delivery(spec, cfg)
+        delivery, delivery_why = self._resolve_delivery(spec, cfg, entry)
         decision["delivery"] = delivery_why
         resolved = dataclasses.replace(
             cfg,
@@ -895,12 +915,9 @@ class Engine:
         if hg is not None:
             hg = spec.init(hg) if spec.init is not None else hg
             spec = spec._replace(hg0=hg)
-        resolved, plan, decision = self.resolve(spec, **overrides)
-        cfg = (
-            dataclasses.replace(self.config, **overrides)
-            if overrides
-            else self.config
-        )
+        entry = self._structure(spec.hg0)[0]
+        resolved, plan, decision = self._resolve(spec, overrides, entry)
+        cfg = self._config(overrides)
         hg0 = spec.hg0
         axes: dict[str, Any] = {}
 
@@ -1013,7 +1030,7 @@ class Engine:
         # Run the cost model even when the axis was pinned or gated, so
         # the non-winning candidate's predicted cost is always visible.
         gate = _non_monoid_reason(spec)
-        _, dwhy = select_delivery(spec, hg0)
+        _, dwhy = select_delivery(spec, hg0, entry.delivery_inputs)
         width = dwhy.get(
             "message_width_bytes", message_width_bytes(spec.initial_msg)
         )
@@ -1069,11 +1086,7 @@ class Engine:
             select_intersect_kernel,
         )
 
-        cfg = (
-            dataclasses.replace(self.config, **overrides)
-            if overrides
-            else self.config
-        )
+        cfg = self._config(overrides)
         pairs, _ = overlap_pairs_with_counts(spec.hg)
         n_pairs = len(pairs)
         resolved, mode, decision = self._resolve_analytics(
@@ -1170,15 +1183,20 @@ class Engine:
         Spans: ``engine.run`` (resolve to value ready) over
         ``engine.resolve``, ``engine.layout_build``, ``engine.dispatch``
         (JAX's ``jax.trace`` / ``jax.lower`` / ``jax.compile`` inside)
-        and ``engine.device_wait``.
+        and ``engine.device_wait``; its ``structure_cache`` arg says
+        whether this incidence's cache entry was there (``hit``) or not
+        (``miss``).
         """
         with maybe_span(self.tracer, "engine.run", cat="execute",
-                        algorithm=getattr(spec, "name", "anonymous")):
-            return self._run(spec, overrides)
+                        algorithm=getattr(spec, "name", "anonymous")) as sp:
+            entry, hit = self._structure(spec.hg0)
+            if sp is not None:
+                sp.args["structure_cache"] = "hit" if hit else "miss"
+            return self._run(spec, overrides, entry)
 
-    def _run(self, spec, overrides: dict) -> Result:
+    def _run(self, spec, overrides: dict, entry) -> Result:
         with maybe_span(self.tracer, "engine.resolve", cat="resolve"):
-            resolved, plan, decision = self.resolve(spec, **overrides)
+            resolved, plan, decision = self._resolve(spec, overrides, entry)
 
         if resolved.representation == "clique":
             t0 = time.perf_counter()
@@ -1198,7 +1216,7 @@ class Engine:
         if resolved.backend == "local":
             fn = compute_jit if resolved.jit else compute
             delivery = (
-                self._delivery_layouts(spec.hg0)
+                self._delivery_layouts(entry)
                 if resolved.delivery == "pallas_fused"
                 else None
             )
@@ -1367,11 +1385,7 @@ class Engine:
                 "Engine.compile serves iterative AlgorithmSpecs; batch "
                 "analytics runs one-shot through Engine.analyze/submit"
             )
-        probe = (
-            dataclasses.replace(self.config, **overrides)
-            if overrides
-            else self.config
-        )
+        probe = self._config(overrides)
         if probe.representation == "clique":
             raise ValueError(
                 "Engine.compile serves the bipartite representation only: "
@@ -1420,7 +1434,8 @@ class Engine:
         point) so an operator can see WHAT the cache holds, not just how
         much; ``sources`` counts live entries by origin (``aot`` |
         ``disk``); ``disk`` mirrors the attached persistent store's counters
-        (``None`` without one).
+        (``None`` without one).  ``structure_hits``/``_misses`` count
+        per-structure cache lookups; ``layout_builds`` its fused layouts.
         """
         sources = Counter(getattr(e, "source", None)
                           for e in self._exec_cache.values())
@@ -1441,6 +1456,9 @@ class Engine:
                 if self.disk_cache is not None
                 else None
             ),
+            "structure_hits": self._structure_hits,
+            "structure_misses": self._structure_misses,
+            "layout_builds": self._layout_builds,
         }
 
     def _note_trace(self) -> None:
@@ -1600,11 +1618,7 @@ class Engine:
         """
         from repro.motifs import overlap_pairs_with_counts
 
-        cfg = (
-            dataclasses.replace(self.config, **overrides)
-            if overrides
-            else self.config
-        )
+        cfg = self._config(overrides)
         pairs, _ = overlap_pairs_with_counts(spec.hg)
         return self._resolve_analytics(spec, cfg, len(pairs))
 
@@ -1617,11 +1631,7 @@ class Engine:
         """
         from repro import motifs
 
-        cfg = (
-            dataclasses.replace(self.config, **overrides)
-            if overrides
-            else self.config
-        )
+        cfg = self._config(overrides)
         # Overlap-pair discovery is the O(sum deg^2) host-side
         # preprocessing step; skip it when nothing consumes it — an
         # explicit pair batch on a pinned bipartite representation

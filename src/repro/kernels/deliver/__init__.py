@@ -33,6 +33,7 @@ from repro.kernels.deliver.layout import (
     DeliveryLayout,
     build_delivery_layout,
     classify_degrees,
+    delivery_structure,
     layout_pair,
     plan_degree_classes,
     plan_ell_width,
@@ -50,6 +51,7 @@ __all__ = [
     "deliver_ell_leaf",
     "deliver_fused_classes",
     "deliver_fused_pallas",
+    "delivery_structure",
     "fused_deliver",
     "layout_pair",
     "plan_degree_classes",

@@ -264,6 +264,54 @@ def plan_degree_classes(
     )
 
 
+def delivery_structure(
+    src, dst, e_mask, n_vertices: int, n_hyperedges: int
+) -> dict:
+    """The structural inputs of the Engine's delivery cost model: the
+    live ``nnz`` and, when any incidence is live, both directions'
+    degree-class plans (``fwd`` combines by ``dst``, ``bwd`` by
+    ``src``) summed three ways — dense slots at the builder's row
+    padding plus residual (``class_work_slots``), the same on the DP's
+    residual-weighted scale, and the single-ELL baseline on that scale
+    — with the larger residual.  A pure function of the incidence, so
+    the Engine computes it once per structure."""
+    src, dst = np.asarray(src), np.asarray(dst)
+    if e_mask is not None:
+        live = np.asarray(e_mask) != 0
+        src, dst = src[live], dst[live]
+    nnz = int(src.shape[0])
+    if nnz == 0:
+        return {"nnz": 0}
+    class_work = class_weighted = single_weighted = 0.0
+    residual = 0
+    plans = {}
+    for side, n_dst, ids in (
+        ("fwd", n_hyperedges, dst), ("bwd", n_vertices, src)
+    ):
+        deg = np.bincount(ids, minlength=n_dst)
+        plan = plan_degree_classes(deg, nnz)
+        k1, rem1 = plan_ell_width(deg, nnz)
+        class_work += float(plan.built_work)
+        class_weighted += float(
+            plan.built_work - plan.residual
+            + RESIDUAL_WEIGHT * plan.residual
+        )
+        single_weighted += float(n_dst * k1 + RESIDUAL_WEIGHT * rem1)
+        residual = max(residual, plan.residual)
+        plans[side] = {
+            "widths": plan.widths, "rows": plan.rows,
+            "residual": plan.residual,
+        }
+    return {
+        "nnz": nnz,
+        "class_work_slots": class_work,
+        "class_weighted_work": class_weighted,
+        "single_ell_weighted_work": single_weighted,
+        "residual": residual,
+        "class_plans": plans,
+    }
+
+
 def classify_degrees(degrees: np.ndarray, widths) -> np.ndarray:
     """Class index per destination under a plan's widths (-1 for
     zero-degree destinations, which own no slot).  Shared by the layout

@@ -35,6 +35,7 @@ The tentpole contracts, asserted:
   distributed backends (the serving subprocess there asserts
   ``supersteps_executed`` agrees with the local backend).
 """
+import dataclasses
 import os
 import subprocess
 import sys
@@ -55,7 +56,9 @@ from repro.core import Engine
 from repro.core.api import Program
 from repro.core.engine import deliver
 from repro.core.executor import select_delivery
+from repro.core.hypergraph import HyperGraph
 from repro.data import powerlaw_hypergraph
+from repro.obs.trace import Tracer
 from repro.kernels.deliver import (
     ClassPlan,
     build_delivery_layout,
@@ -517,14 +520,114 @@ def test_non_monoid_spec_falls_back_and_explicit_raises():
         eng.resolve(spec, delivery="pallas_fused")
 
 
-def test_delivery_layouts_cached_per_structure():
+def _numpy_incidence(hg):
+    return HyperGraph(np.asarray(hg.src), np.asarray(hg.dst),
+                      hg.n_vertices, hg.n_hyperedges)
+
+
+def _permuted_dst(hg):
+    perm = np.random.default_rng(5).permutation(hg.nnz)
+    return HyperGraph.from_coo(np.asarray(hg.src), np.asarray(hg.dst)[perm],
+                               hg.n_vertices, hg.n_hyperedges)
+
+
+# (first, second) hypergraphs: whether their specs share one structure
+# cache entry (and so one fused layout).
+STRUCTURE_PAIRS = {
+    # every spec wraps hg anew (init -> with_attrs): same device arrays
+    "same_hg": (lambda hg: (hg, hg), True),
+    "with_attrs": (lambda hg: (hg, hg.with_attrs(
+        v_attr=jnp.zeros((hg.n_vertices,)))), True),
+    # same nnz, n_vertices and n_hyperedges, another incidence
+    "permuted_dst": (lambda hg: (hg, _permuted_dst(hg)), False),
+    "new_mask": (lambda hg: (hg, dataclasses.replace(
+        hg, e_mask=jnp.ones((hg.nnz,), jnp.float32))), False),
+    "more_vertices": (lambda hg: (hg, dataclasses.replace(
+        hg, n_vertices=hg.n_vertices + 8)), False),
+    # numpy arrays can be mutated in place: wrapper identity only
+    "numpy_arrays": (lambda hg: (_numpy_incidence(hg),) * 2, False),
+}
+
+
+@pytest.mark.parametrize("pair", sorted(STRUCTURE_PAIRS))
+def test_delivery_layouts_cached_per_structure(pair):
+    make, shared = STRUCTURE_PAIRS[pair]
+    first, second = make(medium_hypergraph())
+    eng = Engine(delivery="pallas_fused")
+    eng.run(pagerank_spec(first, iters=4))
+    got = eng.run(pagerank_spec(second, iters=4)).value
+    stats = eng.cache_stats()
+    assert stats["layout_builds"] == (1 if shared else 2), stats
+    assert (stats["structure_hits"], stats["structure_misses"]) == (
+        (1, 1) if shared else (0, 2)), stats
+    want = Engine(delivery="pallas_fused").run(
+        pagerank_spec(second, iters=4)).value
+    for a, b in zip(got, want):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_structure_cache_holds_four_entries():
+    graphs = [powerlaw_hypergraph(40, 30, mean_cardinality=3, seed=s)
+              for s in range(5)]
+    eng = Engine(delivery="pallas_fused")
+    for hg in graphs:
+        eng.run(shortest_paths_spec(hg, 0, 3))
+    assert len(eng._structures) == 4
+    eng.run(shortest_paths_spec(graphs[-1], 0, 3))   # newest: still held
+    eng.run(shortest_paths_spec(graphs[0], 0, 3))    # oldest: evicted
+    stats = eng.cache_stats()
+    assert stats["layout_builds"] == 6, stats
+    assert (stats["structure_hits"], stats["structure_misses"]) == (1, 6)
+    assert len(eng._structures) == 4
+
+
+def test_engine_run_span_reports_structure_cache():
+    tracer = Tracer()
+    eng = Engine(delivery="pallas_fused", tracer=tracer)
     hg = medium_hypergraph()
+    for _ in range(2):
+        eng.run(shortest_paths_spec(hg, 0, 4))
+    runs = [s for s in tracer.spans() if s.name == "engine.run"]
+    assert [s.args["structure_cache"] for s in runs] == ["miss", "hit"]
+    builds = [s for s in tracer.spans() if s.name == "engine.layout_build"]
+    assert len(builds) == 1 and builds[0].parent == runs[0].id
+
+
+def _masked(hg):
+    live = np.random.default_rng(2).random(hg.nnz) > 0.3
+    return dataclasses.replace(hg, e_mask=jnp.asarray(live, jnp.float32))
+
+
+@pytest.mark.parametrize("make_hg,make_spec", [
+    (medium_hypergraph, lambda hg: pagerank_spec(hg, iters=4)),
+    (medium_hypergraph, lambda hg: shortest_paths_spec(hg, 0, 8)),
+    (lambda: _masked(medium_hypergraph()),
+     lambda hg: label_propagation_spec(hg, iters=4)),
+    (lambda: powerlaw_hypergraph(30, 20, mean_cardinality=3, seed=0),
+     lambda hg: pagerank_spec(hg, iters=4)),
+])
+def test_cached_delivery_decision_equals_uncached(make_hg, make_spec):
+    """On a structure-cache hit, ``resolve`` and ``explain`` report the
+    delivery decision an uncached ``select_delivery`` computes."""
+    hg = make_hg()
     eng = Engine()
-    spec = shortest_paths_spec(hg, 0, 8)
-    eng.run(spec, delivery="pallas_fused")
-    lay1 = eng._delivery_layouts(hg)
-    eng.run(spec, delivery="pallas_fused")
-    assert eng._delivery_layouts(hg) is lay1  # identity-cached
+    eng.resolve(make_spec(hg))
+    spec = make_spec(hg)
+    cfg, _, decision = eng.resolve(spec)
+    assert eng.cache_stats()["structure_hits"] == 1
+    choice, why = select_delivery(spec, spec.hg0)
+    assert cfg.delivery == choice
+    assert decision["delivery"] == why
+    axis = eng.explain(spec)["axes"]["delivery"]
+    assert axis == Engine().explain(spec)["axes"]["delivery"]
+    assert axis["winner"] == choice and axis["reason"] == why["reason"]
+    fused = axis["candidates"]["pallas_fused"]
+    for k in ("class_work_slots", "skew_gain", "residual", "class_plans"):
+        assert fused.get(k) == why.get(k), k
+    # the decision owns its plans: editing one leaves the cache intact
+    if "class_plans" in decision["delivery"]:
+        decision["delivery"]["class_plans"]["fwd"]["residual"] = -1
+        assert eng.resolve(spec)[2]["delivery"] == why
 
 
 def test_layout_pair_directions():

@@ -570,7 +570,8 @@ def test_profiled_run_records_the_engine_span_tree(hg, tmp_path):
     assert default_tracer().dropped == 0
     by_id = {s.id: s for s in spans}
     (run,) = [s for s in spans if s.name == "engine.run"]
-    assert run.parent is None and run.args == {"algorithm": "pagerank"}
+    assert run.parent is None and run.args == {
+        "algorithm": "pagerank", "structure_cache": "miss"}
     children = sorted(
         (s for s in spans if s.parent == run.id), key=lambda s: s.t0
     )
