@@ -161,14 +161,14 @@ def _build_layouts():
     src = rng.integers(0, n_src, nnz)
     dst = rng.integers(0, n_dst, nnz)
     out.append(("uniform", build_delivery_layout(
-        src, dst, None, n_src, n_dst,
+        src, dst, None, n_src, n_dst, lowering="pallas",
     )))
     # skewed: a few hubs absorb most edges -> multiple classes
     dst_skew = np.where(
         rng.random(nnz) < 0.6, rng.integers(0, 4, nnz), dst
     )
     out.append(("skewed", build_delivery_layout(
-        src, dst_skew, None, n_src, n_dst,
+        src, dst_skew, None, n_src, n_dst, lowering="pallas",
     )))
     return out
 

@@ -302,34 +302,15 @@ def _stack_layouts(layouts):
     pads); the static grid extents (``class_max_blocks``) and the
     residual-skip count (``rem_nnz``) take the max so one kernel
     serves every shard."""
-    from repro.kernels.deliver import DeliveryLayout
-
-    ref = layouts[0]
-    n_classes = ref.n_classes
-    stack = lambda get: jnp.stack([get(l) for l in layouts])
-    per_class = lambda get: tuple(
-        stack(lambda l, c=c: get(l, c)) for c in range(n_classes)
-    )
-    return DeliveryLayout(
-        class_ell=per_class(lambda l, c: l.class_ell[c]),
-        class_src=per_class(lambda l, c: l.class_src[c]),
-        class_dst=per_class(lambda l, c: l.class_dst[c]),
-        class_bounds=per_class(lambda l, c: l.class_bounds[c]),
-        inv_perm=stack(lambda l: l.inv_perm),
-        rem_src=stack(lambda l: l.rem_src),
-        rem_dst=stack(lambda l: l.rem_dst),
-        n_src=ref.n_src,
-        n_dst=ref.n_dst,
-        nnz=ref.nnz,
+    shared = dict(
         rem_nnz=max(l.rem_nnz for l in layouts),
-        class_widths=ref.class_widths,
-        class_rows=ref.class_rows,
-        block_n=ref.block_n,
-        class_block_e=ref.class_block_e,
         class_max_blocks=tuple(
-            max(l.class_max_blocks[c] for l in layouts)
-            for c in range(n_classes)
+            map(max, zip(*(l.class_max_blocks for l in layouts)))
         ),
+    )
+    return jax.tree.map(
+        lambda *a: jnp.stack(a),
+        *(dataclasses.replace(l, **shared) for l in layouts),
     )
 
 

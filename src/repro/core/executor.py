@@ -48,6 +48,7 @@ from repro.kernels.deliver import (
     DELIVERY_MODES,
     delivery_structure,
     layout_pair,
+    layout_span_args,
     select_lowering,
 )
 
@@ -807,10 +808,12 @@ class Engine:
             return "pallas_fused", {"reason": "explicitly configured"}
         return select_delivery(spec, spec.hg0, entry.delivery_inputs)
 
-    def _delivery_layouts(self, entry):
-        """Both directions' fused layouts for one structure (host-side
-        dst-sort + ELL/CSR precompute), built on its first use."""
-        if entry.layouts is None:
+    def _delivery_layouts(self, entry, sp=None):
+        """Both directions' fused layouts for one structure, built on
+        its first use for the selected lowering (host dst-sort, ELL
+        pack; the CSR form only for Pallas); their counts go on ``sp``."""
+        lowering = select_lowering()
+        if entry.layouts is None or not entry.layouts[0].serves(lowering):
             hg = entry.hg
             with maybe_span(
                 self.tracer, "engine.layout_build", cat="compile",
@@ -819,9 +822,12 @@ class Engine:
             ):
                 entry.layouts = layout_pair(
                     hg.src, hg.dst, hg.e_mask, hg.n_vertices,
-                    hg.n_hyperedges,
+                    hg.n_hyperedges, lowering=lowering,
                 )
             self._layout_builds += 1
+        if sp is not None:
+            sp.args.update(layout_span_args(
+                entry.layouts, entry.delivery_inputs()["nnz"]))
         return entry.layouts
 
     # -- execution ----------------------------------------------------------
@@ -1185,16 +1191,16 @@ class Engine:
         (JAX's ``jax.trace`` / ``jax.lower`` / ``jax.compile`` inside)
         and ``engine.device_wait``; its ``structure_cache`` arg says
         whether this incidence's cache entry was there (``hit``) or not
-        (``miss``).
+        (``miss``); a fused-delivery job adds the ``layout_span_args``.
         """
         with maybe_span(self.tracer, "engine.run", cat="execute",
                         algorithm=getattr(spec, "name", "anonymous")) as sp:
             entry, hit = self._structure(spec.hg0)
             if sp is not None:
                 sp.args["structure_cache"] = "hit" if hit else "miss"
-            return self._run(spec, overrides, entry)
+            return self._run(spec, overrides, entry, sp)
 
-    def _run(self, spec, overrides: dict, entry) -> Result:
+    def _run(self, spec, overrides: dict, entry, sp=None) -> Result:
         with maybe_span(self.tracer, "engine.resolve", cat="resolve"):
             resolved, plan, decision = self._resolve(spec, overrides, entry)
 
@@ -1216,7 +1222,7 @@ class Engine:
         if resolved.backend == "local":
             fn = compute_jit if resolved.jit else compute
             delivery = (
-                self._delivery_layouts(entry)
+                self._delivery_layouts(entry, sp)
                 if resolved.delivery == "pallas_fused"
                 else None
             )

@@ -15,10 +15,13 @@ point).  One fused data path, two lowerings:
   (``repro.analysis.shapes.check_width_gate``).  So it is never
   selected; ``REPRO_DELIVERY_LOWERING`` (``ell`` | ``pallas`` |
   ``pallas_interpret``) reaches it for tests and experiments.
+
+A layout is built for one lowering (``build_delivery_layout``'s
+``lowering``, by default ``select_lowering()``): only a Pallas layout
+carries the kernel's CSR arrays, and it serves both lowerings.
 """
 from __future__ import annotations
 
-import os
 from typing import Any
 
 import jax
@@ -35,8 +38,10 @@ from repro.kernels.deliver.layout import (
     classify_degrees,
     delivery_structure,
     layout_pair,
+    layout_span_args,
     plan_degree_classes,
     plan_ell_width,
+    select_lowering,
     tile_block_bounds,
 )
 from repro.kernels.deliver.xla import deliver_ell_leaf
@@ -54,6 +59,7 @@ __all__ = [
     "delivery_structure",
     "fused_deliver",
     "layout_pair",
+    "layout_span_args",
     "plan_degree_classes",
     "plan_ell_width",
     "select_lowering",
@@ -66,21 +72,13 @@ DELIVERY_MODES = ("auto", "xla", "pallas_fused")
 Pytree = Any
 
 
-def select_lowering() -> str:
-    """``ell`` on every platform; ``REPRO_DELIVERY_LOWERING`` overrides."""
-    forced = os.environ.get("REPRO_DELIVERY_LOWERING")
-    if forced:
-        if forced not in ("ell", "pallas", "pallas_interpret"):
-            raise ValueError(
-                "REPRO_DELIVERY_LOWERING must be ell | pallas | "
-                f"pallas_interpret, got {forced!r}"
-            )
-        return forced
-    return "ell"
-
-
 def _pallas_leaf(leaf, layout, monoid, active, *, interpret):
     """Shape-normalize one leaf for the per-class 2-D Pallas kernels."""
+    if not layout.serves("pallas"):
+        raise ValueError(
+            "this DeliveryLayout was built for the ell lowering and has "
+            "no CSR arrays; build it with lowering='pallas'"
+        )
     shape = leaf.shape
     msgs2d = leaf.reshape(shape[0], -1)
     if monoid.name == "or":

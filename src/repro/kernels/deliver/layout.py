@@ -26,10 +26,12 @@ power-of-two ELL width:
   per-class outputs, so results assemble with one gather — never a
   scatter.  Zero-degree destinations (bucket padding!) own no slot at
   all: they point at an appended identity row.
-* Per class, two synchronized packings of the same dst-sorted edges:
-  a dense ``[rows_c, k_c]`` ELL id table (the XLA lowering's vectorized
-  axis reduce) and a CSR-with-tile-bounds edge list (the Pallas kernel's
-  block-sparse skip, with class-local ``block_e``/grid extents).
+* Per class, a dense ``[rows_c, k_c]`` ELL id table (the XLA
+  lowering's vectorized axis reduce) and, only when the Pallas lowering
+  will run, a CSR-with-tile-bounds edge list of the same dst-sorted
+  edges (the kernel's block-sparse skip, with class-local
+  ``block_e``/grid extents).  The ``ell`` lowering, which every
+  platform selects, never reads the CSR form, so it is not built.
 * Incidences past a hub's class width land in a small dst-sorted COO
   residual (XLA lowering only — the Pallas CSR form has no width cap)
   and take one sorted segment reduce.
@@ -45,6 +47,7 @@ flow through jit / scan / vmap / shard_map as ordinary operands.
 from __future__ import annotations
 
 import dataclasses
+import os
 import time
 from typing import Any
 
@@ -75,6 +78,19 @@ RESIDUAL_WEIGHT = 12.0
 # ``repro.core.serving.bucket_dim`` so serving signatures stay bounded.
 _PAD_FLOOR = 8
 _ROW_FLOOR = 8
+
+
+def select_lowering() -> str:
+    """``ell`` on every platform; ``REPRO_DELIVERY_LOWERING`` overrides."""
+    forced = os.environ.get("REPRO_DELIVERY_LOWERING")
+    if forced:
+        if forced not in ("ell", "pallas", "pallas_interpret"):
+            raise ValueError(
+                "REPRO_DELIVERY_LOWERING must be ell | pallas | "
+                f"pallas_interpret, got {forced!r}"
+            )
+        return forced
+    return "ell"
 
 
 def _pow2_at_least(n: int, floor: int = 1) -> int:
@@ -359,6 +375,10 @@ class DeliveryLayout:
         ``block_n`` rows: (first edge block, n edge blocks) at
         ``class_block_e[c]`` granularity (the block-sparse skip).
 
+    A layout built for the ``ell`` lowering has none of the CSR form:
+    ``class_src``, ``class_dst``, ``class_bounds``, ``class_block_e``
+    and ``class_max_blocks`` are empty (``serves``).
+
     Shared children:
 
       inv_perm: ``[n_dst]`` int32 — destination id -> slot in the
@@ -435,6 +455,12 @@ class DeliveryLayout:
     def rem_len(self) -> int:
         return int(self.rem_src.shape[-1])
 
+    def serves(self, lowering: str) -> bool:
+        """Whether this layout holds what ``lowering`` reads: every
+        layout serves ``ell``; only one with the CSR form serves the
+        Pallas lowerings."""
+        return lowering == "ell" or len(self.class_src) == self.n_classes
+
     def shape_signature(self) -> tuple:
         """Hashable shape tuple for the serving executable cache key —
         covers every class-plan-dependent dim, so a degree-regime shift
@@ -488,9 +514,12 @@ def build_delivery_layout(
     class_rows_pad: tuple | None = None,
     class_nnz_pad: tuple | None = None,
     rem_pad_to: int | None = None,
+    lowering: str | None = None,
 ) -> DeliveryLayout:
     """Build one direction's degree-class layout from a concrete
-    incidence list.
+    incidence list, for ``lowering`` (``select_lowering()`` when None):
+    the per-class CSR edge arrays and tile bounds only for a Pallas
+    lowering, the ELL tables, ``inv_perm`` and residual for every one.
 
     ``src``/``dst``/``e_mask`` are host-transferable arrays (``e_mask``
     may be None).  ``plan=None`` lets ``plan_degree_classes`` pick the
@@ -597,13 +626,15 @@ def build_delivery_layout(
     rem_src[:rem_nnz] = rem_s
     rem_dst[:rem_nnz] = rem_d
 
-    # Per-class dst-sorted CSR edge arrays (Pallas lowering): every live
-    # incidence of the class — hub tails included, the CSR form has no
-    # width cap.  Padding lanes: identity sender, out-of-range row.
+    # Per-class dst-sorted CSR edge arrays, built only for a Pallas
+    # lowering: every live incidence of the class — hub tails included,
+    # the CSR form has no width cap.  Padding lanes: identity sender,
+    # out-of-range row.
     class_src_a, class_dst_a, class_bounds, c_block_e, c_max_blocks = (
         [], [], [], [], [],
     )
-    for c in range(n_classes):
+    csr = (lowering or select_lowering()) != "ell"
+    for c in range(n_classes if csr else 0):
         be = class_block_e(int(widths[c]), block_e)
         sel = s_live & (lane_cls == c) if nnz else np.zeros(0, bool)
         e_src = s_src[sel]
@@ -662,7 +693,8 @@ def layout_pair(
 ) -> tuple[DeliveryLayout, DeliveryLayout]:
     """Both half-superstep directions for one incidence list:
     vertex->hyperedge (combine by ``dst``) and hyperedge->vertex
-    (combine by ``src``)."""
+    (combine by ``src``); ``kw`` (``lowering`` among them) goes to
+    ``build_delivery_layout``."""
     fwd = build_delivery_layout(
         hg_src, hg_dst, e_mask, n_vertices, n_hyperedges, **kw
     )
@@ -670,6 +702,22 @@ def layout_pair(
         hg_dst, hg_src, e_mask, n_hyperedges, n_vertices, **kw
     )
     return fwd, bwd
+
+
+def layout_span_args(layouts, live_nnz: int) -> dict:
+    """What a job that delivered through ``layouts`` (a ``layout_pair``)
+    records on its ``engine.run`` span: its ``live_nnz`` incidences, the
+    ``delivery_lanes`` both directions' scans touch (dense ELL slots
+    plus residual lanes, what ``delivery.ell_slots`` and
+    ``delivery.residual_lanes`` add at build time) and the pair's device
+    ``layout_bytes``."""
+    return {
+        "live_nnz": int(live_nnz),
+        "delivery_lanes": sum(l.ell_slots + l.rem_len for l in layouts),
+        "layout_bytes": sum(
+            int(a.nbytes) for a in jax.tree.leaves(tuple(layouts))
+        ),
+    }
 
 
 Pytree = Any

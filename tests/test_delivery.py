@@ -123,7 +123,8 @@ def test_fused_delivery_bitwise_equals_reference(case):
         e_mask=jnp.asarray(mask) if mask is not None else None,
     )
     layout = build_delivery_layout(
-        src, dst, mask, n_src, n_dst, block_n=8, block_e=16
+        src, dst, mask, n_src, n_dst, block_n=8, block_e=16,
+        lowering="pallas_interpret",
     )
     for lowering in ("ell", "pallas_interpret"):
         got = fused_deliver(
@@ -143,10 +144,12 @@ def test_fused_delivery_padded_layout_invariance(case):
     prog = Program(procedure=lambda *a: None, combiner=monoid)
     act_j = jnp.asarray(active) if active is not None else None
     base = build_delivery_layout(
-        src, dst, mask, n_src, n_dst, block_n=8, block_e=16
+        src, dst, mask, n_src, n_dst, block_n=8, block_e=16,
+        lowering="pallas_interpret",
     )
     padded = build_delivery_layout(
         src, dst, mask, n_src, n_dst, block_n=8, block_e=16,
+        lowering="pallas_interpret",
         plan=ClassPlan(
             widths=base.class_widths,
             rows=tuple(int(r) for r in base.class_rows),
@@ -181,7 +184,9 @@ def test_fused_float_sum_within_reassociation_tolerance():
         jnp.asarray(msg), None, jnp.asarray(src), jnp.asarray(dst),
         n_dst, prog,
     )
-    layout = build_delivery_layout(src, dst, None, n_src, n_dst)
+    layout = build_delivery_layout(
+        src, dst, None, n_src, n_dst, lowering="pallas_interpret"
+    )
     for lowering in ("ell", "pallas_interpret"):
         got = fused_deliver(
             jnp.asarray(msg), None, layout, prog, lowering=lowering
@@ -282,7 +287,7 @@ def _assert_fused_matches_reference(src, dst, mask, n_src, n_dst,
     if layout is None:
         layout = build_delivery_layout(
             src, dst, mask, n_src, n_dst, block_n=8, block_e=16,
-            **build_kw,
+            lowering="pallas_interpret", **build_kw,
         )
     for monoid in MONOIDS_UNDER_TEST:
         if monoid == "or":
@@ -369,6 +374,7 @@ def test_pathological_all_overflow_forced_plan():
     layout = build_delivery_layout(
         src, dst, None, n_src, n_dst, block_n=8, block_e=16,
         plan=ClassPlan(widths=(1,), rows=(n_dst,), residual=nnz - n_dst),
+        lowering="pallas_interpret",
     )
     assert layout.rem_nnz > 0.9 * nnz
     _assert_fused_matches_reference(
@@ -383,7 +389,8 @@ def test_pathological_zero_degree_destinations_read_identity():
     dst = np.array([2, 2, 2, 2], np.int32)  # only dst 2 is live
     n_src, n_dst = 4, 9
     layout = build_delivery_layout(
-        src, dst, None, n_src, n_dst, block_n=8, block_e=16
+        src, dst, None, n_src, n_dst, block_n=8, block_e=16,
+        lowering="pallas_interpret",
     )
     # every empty destination shares the single identity slot
     inv = np.asarray(layout.inv_perm)
@@ -397,11 +404,14 @@ def test_pathological_zero_degree_destinations_read_identity():
         assert np.isposinf(out[np.arange(n_dst) != 2]).all()
 
 
-def test_shard_harmonized_class_plans_stack():
+def test_shard_harmonized_class_plans_stack(monkeypatch):
     """``build_shard_delivery``: one merged-histogram plan, per-class
     pads harmonized to maxima — layouts stack, and a hub destination on
-    the shard boundary stays dense on every shard that sees it."""
+    the shard boundary stays dense on every shard that sees it.  Built
+    for the Pallas lowering, so the CSR arrays stack too."""
     from repro.core.distributed import build_shard_delivery
+
+    monkeypatch.setenv("REPRO_DELIVERY_LOWERING", "pallas_interpret")
 
     rng = np.random.default_rng(4)
     n_parts, shard_len = 4, 256

@@ -570,8 +570,19 @@ def test_profiled_run_records_the_engine_span_tree(hg, tmp_path):
     assert default_tracer().dropped == 0
     by_id = {s.id: s for s in spans}
     (run,) = [s for s in spans if s.name == "engine.run"]
+    # a fused-delivery job also records its layouts' counts (ell build:
+    # ELL tables, inv_perm and residual, no CSR arrays)
+    layouts = eng._structures[-1].layouts
+    live = hg.nnz if hg.e_mask is None else int(
+        np.count_nonzero(np.asarray(hg.e_mask)))
     assert run.parent is None and run.args == {
-        "algorithm": "pagerank", "structure_cache": "miss"}
+        "algorithm": "pagerank", "structure_cache": "miss",
+        "live_nnz": live,
+        "delivery_lanes": sum(l.ell_slots + l.rem_len for l in layouts),
+        "layout_bytes": sum(
+            sum(t.nbytes for t in l.class_ell) + l.inv_perm.nbytes
+            + l.rem_src.nbytes + l.rem_dst.nbytes for l in layouts),
+    }
     children = sorted(
         (s for s in spans if s.parent == run.id), key=lambda s: s.t0
     )
