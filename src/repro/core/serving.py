@@ -717,7 +717,8 @@ class CompiledAlgorithm:
         # once per (hypergraph, bucket) — the serve loop never re-sorts.
         # Built from the PADDED structure (padding lanes carry e_mask=0
         # and fold to identity), so the layouts match the executable's
-        # shapes; their data-dependent dims enter the cache signature.
+        # shapes; their data-dependent dims enter the cache signature,
+        # so class rows and residual are bucketed to powers of two too.
         delivery = None
         delivery_sig = None
         if cfg.delivery == "pallas_fused":
@@ -733,7 +734,8 @@ class CompiledAlgorithm:
                     )
                 if cfg.backend == "local":
                     delivery = layout_pair(
-                        hgp.src, hgp.dst, hgp.e_mask, nv_pad, ne_pad
+                        hgp.src, hgp.dst, hgp.e_mask, nv_pad, ne_pad,
+                        bucketed=True,
                     )
                 else:
                     from repro.core.distributed import build_shard_delivery

@@ -26,11 +26,11 @@ power-of-two ELL width:
   per-class outputs, so results assemble with one gather — never a
   scatter.  Zero-degree destinations (bucket padding!) own no slot at
   all: they point at an appended identity row.
-* Per class, a dense ``[rows_c, k_c]`` ELL id table (the XLA
-  lowering's vectorized axis reduce) and, only when the Pallas lowering
-  will run, a CSR-with-tile-bounds edge list of the same dst-sorted
-  edges (the kernel's block-sparse skip, with class-local
-  ``block_e``/grid extents).  The ``ell`` lowering, which every
+* Per class, a dense slot-major ``[k_c, rows_c]`` ELL id table (the
+  XLA lowering's vectorized reduce over slots) and, only when the
+  Pallas lowering will run, a CSR-with-tile-bounds edge list of the
+  same dst-sorted edges (the kernel's block-sparse skip, with
+  class-local ``block_e``/grid extents).  The ``ell`` lowering, which every
   platform selects, never reads the CSR form, so it is not built.
 * Incidences past a hub's class width land in a small dst-sorted COO
   residual (XLA lowering only — the Pallas CSR form has no width cap)
@@ -74,8 +74,9 @@ CLASS_K_CAP = 65536
 # ~125M slots/s vs ~11M lanes/s through the sorted scatter, so the DP
 # prices a residual lane at ~12 dense slots and keeps hubs dense.
 RESIDUAL_WEIGHT = 12.0
-# Remainder / padded-row buckets: pow2 with a small floor, mirroring
-# ``repro.core.serving.bucket_dim`` so serving signatures stay bounded.
+# A class's rows and the residual's lanes are allocated in multiples of
+# these, not in powers of two: the scan gathers every allocated lane,
+# so padding lanes cost as much as live ones.
 _PAD_FLOOR = 8
 _ROW_FLOOR = 8
 
@@ -98,6 +99,28 @@ def _pow2_at_least(n: int, floor: int = 1) -> int:
     while b < n:
         b *= 2
     return b
+
+
+def _padded_len(n: int, floor: int) -> int:
+    """What a layout allocates for ``n`` real class rows (``floor`` is
+    ``_ROW_FLOOR``) or residual lanes (``_PAD_FLOOR``): the smallest
+    multiple of ``floor`` at or above ``n``, and at least ``floor``.
+    ``ClassPlan.built_work`` and ``build_delivery_layout`` both pad
+    through it, so the cost model prices what the layout executes."""
+    f = int(floor)
+    return max(-(-int(n) // f), 1) * f
+
+
+def _live_degrees(dst, e_mask, n_dst: int):
+    """``(live mask, live degree per destination)`` of an incidence."""
+    dst = np.asarray(dst, np.int64)
+    live = (
+        np.asarray(e_mask) != 0
+        if e_mask is not None
+        else np.ones(len(dst), bool)
+    )
+    deg = np.bincount(dst[live], minlength=max(n_dst, 1))[:n_dst]
+    return live, deg
 
 
 def _width_stats(degrees: np.ndarray, k_cap: int):
@@ -187,20 +210,18 @@ class ClassPlan:
 
     @property
     def built_rows(self) -> tuple:
-        """Per-class row counts as ``build_delivery_layout`` will pad
-        them (pow2, floor ``_ROW_FLOOR``) — what the tables really
-        allocate."""
-        return tuple(
-            _pow2_at_least(max(int(r), 1), _ROW_FLOOR) for r in self.rows
-        )
+        """Per-class row counts as ``build_delivery_layout`` pads them
+        (``_padded_len``) — what the tables really allocate."""
+        return tuple(_padded_len(r, _ROW_FLOOR) for r in self.rows)
 
     @property
     def built_work(self) -> int:
-        """Dense slots + residual at the BUILDER's row padding — the
-        work a layout built from this plan actually executes (the cost
-        model budgets on this, not the tighter DP-count ``work``)."""
+        """Dense slots + residual lanes at the BUILDER's padding — the
+        work a layout built from this plan actually executes, its
+        ``ell_slots + rem_len`` (the cost model budgets on this, not
+        the tighter DP-count ``work``)."""
         dense = sum(r * k for r, k in zip(self.built_rows, self.widths))
-        return int(dense) + int(self.residual)
+        return int(dense) + _padded_len(self.residual, _PAD_FLOOR)
 
     @property
     def weighted_work(self) -> float:
@@ -363,9 +384,10 @@ class DeliveryLayout:
     under the distributed executor).  Per degree class ``c`` (tuples of
     length ``n_classes``):
 
-      class_ell[c]: ``[rows_c, k_c]`` int32 — the class's destinations'
-        first-``k_c`` sender ids, one row per destination slot (identity
-        row ``n_src`` in empty slots).  The XLA lowering's dense table.
+      class_ell[c]: ``[k_c, rows_c]`` int32 — the class's destinations'
+        first-``k_c`` sender ids, slot-major: column ``r`` is the
+        destination in slot ``r`` (identity row ``n_src`` in empty
+        slots).  The XLA lowering's dense table.
       class_src[c] / class_dst[c]: ``[nnz_c_pad]`` int32 — ALL the
         class's live incidences in dst-sorted order: sender id and
         class-LOCAL destination row (padding lanes: identity sender,
@@ -525,26 +547,18 @@ def build_delivery_layout(
     may be None).  ``plan=None`` lets ``plan_degree_classes`` pick the
     class boundaries and widths from the live-degree histogram; the
     distributed builder passes a shared plan so shard layouts agree.
-    ``class_rows_pad`` / ``class_nnz_pad`` / ``rem_pad_to`` force the
-    per-class row counts, edge-array lengths and residual pad (each >=
-    what this shard needs) so per-shard layouts stack into one
-    shard_map operand.
+    By default a class's rows and the residual are padded to a multiple
+    of 8 (``_padded_len``).  ``class_rows_pad`` / ``class_nnz_pad`` /
+    ``rem_pad_to`` force the per-class row counts, edge-array lengths
+    and residual pad (each >= what the incidence needs): per-shard
+    layouts stack into one shard_map operand, and serving's layouts
+    keep bucketed shapes (``layout_pair(bucketed=True)``).
     """
     t_build0 = time.perf_counter()
     src = np.asarray(src, np.int64)
     dst = np.asarray(dst, np.int64)
     nnz = len(src)
-    live = (
-        np.asarray(e_mask) != 0
-        if e_mask is not None
-        else np.ones(nnz, bool)
-    )
-
-    live_deg = (
-        np.bincount(dst[live], minlength=max(n_dst, 1))[:n_dst]
-        if nnz
-        else np.zeros(max(n_dst, 1), np.int64)[:n_dst]
-    )
+    live, live_deg = _live_degrees(dst, e_mask, n_dst)
     n_live = int(live.sum())
     if plan is None:
         plan = plan_degree_classes(live_deg, n_live)
@@ -556,9 +570,7 @@ def build_delivery_layout(
         cls[cls >= 0], minlength=n_classes
     )[:n_classes]
     if class_rows_pad is None:
-        rows_pad = tuple(
-            _pow2_at_least(max(int(r), 1), _ROW_FLOOR) for r in rows_real
-        )
+        rows_pad = tuple(_padded_len(r, _ROW_FLOOR) for r in rows_real)
     else:
         rows_pad = tuple(int(r) for r in class_rows_pad)
         assert all(p >= r for p, r in zip(rows_pad, rows_real)), (
@@ -600,14 +612,14 @@ def build_delivery_layout(
         live_rank = np.zeros(0, np.int64)
         in_ell = over = np.zeros(0, bool)
 
-    # Per-class ELL tables (XLA lowering).
+    # Per-class slot-major ELL tables (XLA lowering).
     class_ell = []
     for c in range(n_classes):
-        tbl = np.full((rows_pad[c], int(widths[c])), n_src, np.int32)
+        tbl = np.full((int(widths[c]), rows_pad[c]), n_src, np.int32)
         sel = in_ell & (lane_cls == c)
         if sel.any():
             r_local = inv_perm[s_dst[sel]] - base[c]
-            tbl[r_local, live_rank[sel]] = s_src[sel]
+            tbl[live_rank[sel], r_local] = s_src[sel]
         class_ell.append(tbl)
 
     # Residual COO (dst-sorted: the scan order preserves it).  Padding
@@ -620,7 +632,7 @@ def build_delivery_layout(
         assert rem_pad_to >= rem_nnz, (rem_pad_to, rem_nnz)
         rem_pad = int(rem_pad_to)
     else:
-        rem_pad = _pow2_at_least(max(rem_nnz, 1), _PAD_FLOOR)
+        rem_pad = _padded_len(rem_nnz, _PAD_FLOOR)
     rem_src = np.full(rem_pad, n_src, np.int32)
     rem_dst = np.full(rem_pad, max(n_dst - 1, 0), np.int32)
     rem_src[:rem_nnz] = rem_s
@@ -688,18 +700,40 @@ def build_delivery_layout(
     return layout
 
 
+def _bucketed_pads(dst, e_mask, n_dst: int) -> dict:
+    """Forcing arguments that pad each class's rows and the residual to
+    powers of two, as ``repro.core.serving.bucket_dim`` pads the
+    structure."""
+    live, deg = _live_degrees(dst, e_mask, n_dst)
+    plan = plan_degree_classes(deg, int(live.sum()))
+    return {
+        "plan": plan,
+        "class_rows_pad": tuple(
+            _pow2_at_least(max(r, 1), _ROW_FLOOR) for r in plan.rows
+        ),
+        "rem_pad_to": _pow2_at_least(max(plan.residual, 1), _PAD_FLOOR),
+    }
+
+
 def layout_pair(
-    hg_src, hg_dst, e_mask, n_vertices: int, n_hyperedges: int, **kw
+    hg_src, hg_dst, e_mask, n_vertices: int, n_hyperedges: int, *,
+    bucketed: bool = False, **kw
 ) -> tuple[DeliveryLayout, DeliveryLayout]:
     """Both half-superstep directions for one incidence list:
     vertex->hyperedge (combine by ``dst``) and hyperedge->vertex
     (combine by ``src``); ``kw`` (``lowering`` among them) goes to
-    ``build_delivery_layout``."""
+    ``build_delivery_layout``.  ``bucketed`` pads rows and residual to
+    powers of two, so that hypergraphs of one serving bucket share
+    layout shapes and so one executable (``CompiledAlgorithm``)."""
     fwd = build_delivery_layout(
-        hg_src, hg_dst, e_mask, n_vertices, n_hyperedges, **kw
+        hg_src, hg_dst, e_mask, n_vertices, n_hyperedges, **kw,
+        **(_bucketed_pads(hg_dst, e_mask, n_hyperedges) if bucketed
+           else {}),
     )
     bwd = build_delivery_layout(
-        hg_dst, hg_src, e_mask, n_hyperedges, n_vertices, **kw
+        hg_dst, hg_src, e_mask, n_hyperedges, n_vertices, **kw,
+        **(_bucketed_pads(hg_src, e_mask, n_vertices) if bucketed
+           else {}),
     )
     return fwd, bwd
 

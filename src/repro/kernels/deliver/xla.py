@@ -5,11 +5,17 @@ into the layout, message rows read once, combine without a serialized
 scatter — but lowered through stock XLA ops.  This is the lowering
 every platform runs, the TPU included:
 
-* each degree class's incidences sit in its own dense ``[rows_c, k_c]``
-  id table: one vectorized gather and one dense axis reduction per
-  class replace the scatter (XLA's CPU scatter-add serializes; a
-  ``[rows_c, k_c, D]`` reduce vectorizes).  Class widths track the
-  degree histogram, so hubs stay dense and the tail stays narrow;
+* each degree class's incidences sit in its own dense slot-major
+  ``[k_c, rows_c]`` id table: one vectorized gather and one dense
+  reduction over the ``k_c`` slots per class replace the scatter
+  (XLA's CPU scatter-add serializes; a ``[k_c, rows_c, D]`` reduce
+  vectorizes).  Slot-major keeps the destinations on the minor axis,
+  so a class may hold any multiple of 8 rows: row-major
+  ``[rows_c, k_c]`` tables whose row counts were not powers of two
+  made the v5e compiler emit a program 16x larger (184 MB against
+  11.5 MB at dblp's size), reloaded on every retrace.  Class widths
+  track the degree histogram, so hubs stay dense and the tail stays
+  narrow;
 * the per-class partials concatenate (plus one identity row for
   zero-degree destinations) and assemble with ONE gather through the
   layout's ``inv_perm`` — no scatter anywhere on the dense path;
@@ -19,7 +25,7 @@ every platform runs, the TPU included:
 
 Statically-dead lanes were dropped at layout-build time, so only a
 dynamic ``active`` vector costs a mask here — and it is a per-class
-``[rows_c, k_c]`` byte mask, not an ``[nnz, D]`` float ``where``.
+``[k_c, rows_c]`` byte mask, not an ``[nnz, D]`` float ``where``.
 """
 from __future__ import annotations
 
@@ -36,10 +42,10 @@ _AXIS_REDUCE = {
 }
 
 
-def _reduce_axis1(x: jnp.ndarray, monoid: Monoid) -> jnp.ndarray:
+def _reduce_axis0(x: jnp.ndarray, monoid: Monoid) -> jnp.ndarray:
     if monoid.name == "or":
-        return jnp.any(x, axis=1)
-    return _AXIS_REDUCE[monoid.name](x, axis=1)
+        return jnp.any(x, axis=0)
+    return _AXIS_REDUCE[monoid.name](x, axis=0)
 
 
 def deliver_ell_leaf(
@@ -63,14 +69,14 @@ def deliver_ell_leaf(
 
     outs = []
     for ell in layout.class_ell:
-        rows_c, k = ell.shape
+        k, rows_c = ell.shape
         rows = jnp.take(
             msgs_aug, ell.reshape(-1), axis=0
-        ).reshape((rows_c, k) + msgs.shape[1:])
+        ).reshape((k, rows_c) + msgs.shape[1:])
         if act_aug is not None:
-            live = jnp.take(act_aug, ell, axis=0)  # [rows_c, k]
-            rows = jnp.where(live.reshape((rows_c, k) + trail), rows, ident)
-        outs.append(_reduce_axis1(rows, monoid))
+            live = jnp.take(act_aug, ell, axis=0)  # [k, rows_c]
+            rows = jnp.where(live.reshape((k, rows_c) + trail), rows, ident)
+        outs.append(_reduce_axis0(rows, monoid))
     # Assembly is a pure gather: slot order is class-major, and the
     # appended identity row serves every zero-degree destination.
     out = jnp.take(

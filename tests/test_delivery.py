@@ -430,7 +430,7 @@ def test_shard_harmonized_class_plans_stack(monkeypatch):
         assert lay.inv_perm.shape[0] == n_parts
         for c in range(lay.n_classes):
             assert lay.class_ell[c].shape[0] == n_parts
-            assert lay.class_ell[c].shape[2] == lay.class_widths[c]
+            assert lay.class_ell[c].shape[1] == lay.class_widths[c]
             assert lay.class_src[c].shape[0] == n_parts
     # the hub's shard-local degree fits its class width on every shard
     live = np.asarray(mask) != 0
@@ -648,6 +648,104 @@ def test_layout_pair_directions():
     assert (fwd.n_src, fwd.n_dst) == (hg.n_vertices, hg.n_hyperedges)
     assert (bwd.n_src, bwd.n_dst) == (hg.n_hyperedges, hg.n_vertices)
     assert fwd.nnz == bwd.nnz == hg.nnz
+
+
+# --------------------------------------------------------------------------
+# row and residual padding: multiples of 8 by default
+# --------------------------------------------------------------------------
+
+def _pow2(n, floor=8):
+    return max(1 << (max(int(n), 1) - 1).bit_length(), floor)
+
+
+def _up8(n):
+    return max(-(-int(n) // 8) * 8, 8)
+
+
+def _friendster_like():
+    from repro.data.generators import DATASET_REGIMES
+
+    r = DATASET_REGIMES["friendster"]
+    return powerlaw_hypergraph(8000, 1600, r.mean_cardinality,
+                               r.cardinality_alpha, r.popularity_alpha,
+                               seed=0)
+
+
+PADDING_GRAPHS = {
+    "skewed": medium_hypergraph,
+    "residual_both_ways": _friendster_like,
+}
+
+
+def _directions(hg):
+    """(senders, receivers, n_src, n_dst) for both directions."""
+    src, dst = np.asarray(hg.src), np.asarray(hg.dst)
+    return ((src, dst, hg.n_vertices, hg.n_hyperedges),
+            (dst, src, hg.n_hyperedges, hg.n_vertices))
+
+
+def _real_rows(d, n_dst, widths):
+    cls = classify_degrees(np.bincount(d, minlength=n_dst), widths)
+    return tuple(int((cls == c).sum()) for c in range(len(widths)))
+
+
+@pytest.mark.parametrize("graph", sorted(PADDING_GRAPHS))
+def test_default_build_pads_rows_and_residual_to_multiples_of_8(graph):
+    hg = PADDING_GRAPHS[graph]()
+    rems = []
+    for s, d, n_src, n_dst in _directions(hg):
+        plan = plan_degree_classes(np.bincount(d, minlength=n_dst), hg.nnz)
+        lay = build_delivery_layout(s, d, None, n_src, n_dst)
+        real = _real_rows(d, n_dst, lay.class_widths)
+        assert lay.class_rows == tuple(_up8(r) for r in real)
+        assert lay.rem_len == _up8(lay.rem_nnz)
+        assert plan.built_rows == lay.class_rows
+        assert plan.built_work == lay.ell_slots + lay.rem_len
+        rems.append(lay.rem_nnz)
+    if graph == "residual_both_ways":
+        assert min(rems) > 0
+
+
+def _pow2_build(s, d, n_src, n_dst, tight):
+    """``tight``'s incidence rebuilt with power-of-two rows and
+    residual, through the builder's forcing arguments."""
+    plan = ClassPlan(widths=tight.class_widths,
+                     rows=_real_rows(d, n_dst, tight.class_widths),
+                     residual=tight.rem_nnz)
+    return build_delivery_layout(
+        s, d, None, n_src, n_dst, plan=plan,
+        class_rows_pad=tuple(_pow2(r) for r in plan.rows),
+        rem_pad_to=_pow2(plan.residual),
+    )
+
+
+@pytest.mark.parametrize("graph", sorted(PADDING_GRAPHS))
+@pytest.mark.parametrize("combiner", ["sum", "min", "max", "two_leaves"])
+def test_tight_build_delivers_bitwise_the_pow2_build(graph, combiner):
+    """Padding lanes gather the identity row and reduction order is
+    unchanged, so a multiple-of-8 build delivers bitwise what a
+    power-of-two build of the same incidence delivers."""
+    hg = PADDING_GRAPHS[graph]()
+    rng = np.random.default_rng(11)
+    for s, d, n_src, n_dst in _directions(hg):
+        tight = build_delivery_layout(s, d, None, n_src, n_dst)
+        wide = _pow2_build(s, d, n_src, n_dst, tight)
+        assert wide.ell_slots + wide.rem_len > tight.ell_slots + tight.rem_len
+        if combiner == "two_leaves":
+            prog = Program(procedure=lambda *a: None, combiner="sum")
+            msg = (rng.standard_normal(n_src).astype(np.float32),
+                   rng.standard_normal((n_src, 2)).astype(np.float32))
+        else:
+            prog = Program(procedure=lambda *a: None, combiner=combiner)
+            msg = rng.standard_normal(n_src).astype(np.float32)
+        msg = jax.tree.map(jnp.asarray, msg)
+        active = jnp.asarray(rng.random(n_src) > 0.2)
+        for act in (None, active):
+            a = fused_deliver(msg, act, tight, prog, lowering="ell")
+            b = fused_deliver(msg, act, wide, prog, lowering="ell")
+            for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b),
+                            strict=True):
+                assert np.array_equal(np.asarray(x), np.asarray(y))
 
 
 # --------------------------------------------------------------------------

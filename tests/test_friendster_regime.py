@@ -136,3 +136,23 @@ def test_engine_run_span_records_the_layout_counts(hg, monkeypatch):
     for s in runs[:2]:
         assert {k: s.args[k] for k in want} == want
     assert not set(want) & set(runs[2].args)
+    # rows and residual padded to multiples of 8, not to powers of two
+    lanes = want["delivery_lanes"] / (2 * hg.nnz)
+    assert lanes < _pow2_lanes(hg, layouts) / (2 * hg.nnz)
+
+
+def _pow2_lanes(hg, layouts):
+    """The lanes of ``layouts``' classes with each class's real rows and
+    the residual padded to the next power of two (at least 8)."""
+    pow2 = lambda n: max(1 << (max(int(n), 1) - 1).bit_length(), 8)
+    src, dst = np.asarray(hg.src), np.asarray(hg.dst)
+    total = 0
+    for ids, n_dst, lay in ((dst, hg.n_hyperedges, layouts[0]),
+                            (src, hg.n_vertices, layouts[1])):
+        deg = np.bincount(ids, minlength=n_dst)
+        widths = np.asarray(lay.class_widths)
+        cls = np.minimum(np.searchsorted(widths, deg), len(widths) - 1)
+        for c, k in enumerate(widths):
+            total += pow2(((cls == c) & (deg > 0)).sum()) * int(k)
+        total += pow2(np.maximum(deg - widths[-1], 0).sum())
+    return total

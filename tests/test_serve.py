@@ -445,6 +445,66 @@ def test_cache_stats_evictions_and_entry_shapes():
 
 
 # --------------------------------------------------------------------------
+# fused delivery under serving: bucketed layout shapes
+# --------------------------------------------------------------------------
+
+def _is_pow2(n):
+    return n >= 8 and n & (n - 1) == 0
+
+
+def _regular_hypergraph(n_hyperedges):
+    """Every vertex in 2 hyperedges, every hyperedge of 4 vertices."""
+    from repro.core.hypergraph import HyperGraph
+
+    v = np.arange(2 * n_hyperedges, dtype=np.int32)
+    src = np.concatenate([v, v])
+    dst = np.concatenate([v % n_hyperedges, (v + 1) % n_hyperedges])
+    return HyperGraph.from_coo(src, dst, 2 * n_hyperedges, n_hyperedges)
+
+
+def test_compiled_fused_layouts_keep_pow2_rows_and_residual():
+    from repro.algorithms import shortest_paths_spec
+    from repro.analysis.retrace import _same_bucket_pair
+
+    hg, hg2 = _same_bucket_pair()
+    comp = Engine().compile(shortest_paths_spec(hg, 0, 8),
+                            delivery="pallas_fused")
+    for g in (hg, hg2):
+        for lay in comp._prepared(g, rebind=False)["delivery"]:
+            assert all(_is_pow2(r) for r in lay.class_rows), lay.class_rows
+            assert _is_pow2(lay.rem_len), lay.rem_len
+
+
+def test_same_bucket_hypergraphs_share_one_fused_executable():
+    """Two hypergraphs of one bucket whose classes hold different real
+    row counts (20 and 24 hyperedges, 40 and 48 vertices) share one
+    ``delivery_sig`` and so one executable; a multiple-of-8 build would
+    give them different shapes."""
+    import jax
+
+    from repro.algorithms import shortest_paths_spec
+    from repro.kernels.deliver import layout_pair
+
+    small, large = _regular_hypergraph(20), _regular_hypergraph(24)
+    eng = Engine()
+    comp = eng.compile(shortest_paths_spec(small, 0, 8),
+                       delivery="pallas_fused")
+    preps = [comp._prepared(g, rebind=False) for g in (small, large)]
+    assert preps[0]["delivery_sig"] == preps[1]["delivery_sig"]
+    tight = [layout_pair(p["hgp"].src, p["hgp"].dst, p["hgp"].e_mask,
+                         p["nv_pad"], p["ne_pad"])[1] for p in preps]
+    assert tight[0].class_rows != tight[1].class_rows
+    comp.run()
+    traces = eng.cache_stats()["traces"]
+    got = comp.run(large).value
+    assert eng.cache_stats()["traces"] == traces
+    want = Engine().run(shortest_paths_spec(large, 0, 8),
+                        delivery="xla").value
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+
+
+# --------------------------------------------------------------------------
 # metrics
 # --------------------------------------------------------------------------
 

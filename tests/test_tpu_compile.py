@@ -123,6 +123,45 @@ def test_local_pagerank_superstep_program_compiles_for_v5e(
     assert "tpu_custom_call" not in compiled.as_text()  # no Pallas kernel
 
 
+def test_superstep_program_size_does_not_follow_the_row_padding(
+    one_chip, dblp, padded_layouts
+):
+    """Classes padded to multiples of 8 rows compile to about the
+    program that power-of-two rows give.  Row-major ``[rows, k]``
+    tables made the v5e compiler emit 16 times the code for row counts
+    that were not powers of two, and ``Engine.run`` loads that program
+    again on every job."""
+    from jax.experimental.serialize_executable import serialize
+
+    from repro import algorithms as alg
+    from repro.core.engine import compute
+    from repro.kernels.deliver import layout_pair
+
+    dims, tight = padded_layouts
+    spec = alg.pagerank_spec(dblp, iters=10)
+    hgp = spec.hg0.padded(*dims)
+    pow2 = layout_pair(hgp.src, hgp.dst, hgp.e_mask, *dims[:2],
+                       bucketed=True)
+    assert any(r & (r - 1) for l in tight for r in l.class_rows)
+    assert all(not r & (r - 1) for l in pow2 for r in l.class_rows)
+
+    def program(hgp, layouts):
+        out = compute(
+            hgp, max_iters=10, initial_msg=spec.initial_msg,
+            v_program=spec.v_program, he_program=spec.he_program,
+            delivery=layouts,
+        )
+        return out.v_attr, out.he_attr
+
+    size = {}
+    for name, layouts in (("tight", tight), ("pow2", pow2)):
+        compiled = jax.jit(program).lower(
+            *_abstract((hgp, layouts), one_chip)
+        ).compile()
+        size[name] = len(serialize(compiled)[0])
+    assert size["tight"] < 2 * size["pow2"], size
+
+
 def test_sssp_batch16_executable_compiles_for_v5e(
     one_chip, dblp, padded_layouts
 ):
