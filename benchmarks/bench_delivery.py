@@ -46,9 +46,8 @@ Five regimes probe the cost model's axes (message width, degree skew):
   path, and the class layout must not regress the single-ELL packing
   (asserted).
 
-Asserts here are calibrated for the XLA (ELL) lowering, the one
-every platform runs; the Pallas kernel is reachable only through
-``REPRO_DELIVERY_LOWERING``.
+Asserts here are calibrated for the XLA (ELL) lowering, fused
+delivery's one lowering on every platform.
 
 Writes ``BENCH_delivery.json`` (uploaded by the nightly CI job).
 """

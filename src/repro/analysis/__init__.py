@@ -1,6 +1,6 @@
 """Static analysis for the compile-once seam: ``python -m repro.analysis``.
 
-Four passes over the invariants MESH's flexibility bargain rests on:
+Three passes over the invariants MESH's flexibility bargain rests on:
 
 * ``lint`` — AST rules (traced-cond, host-sync vs the hot-path
   inventory, static-arg-array, tracer-gate) over ``src/repro``;
@@ -8,9 +8,7 @@ Four passes over the invariants MESH's flexibility bargain rests on:
   paths (also exported as the ``assert_no_retrace`` guard and the
   ``no_retrace`` pytest fixture);
 * ``digest`` — ``stable_digest`` identity / collision / cross-process
-  determinism over a spec x config x bucket grid;
-* ``shapes`` — ``jax.eval_shape`` agreement between the two delivery
-  lowerings plus static VMEM tile budgets.
+  determinism over a spec x config x bucket grid.
 
 Findings diff against ``tools/analysis_baseline.json`` so pre-existing
 accepted findings never block CI; new ones do.

@@ -1,4 +1,4 @@
-"""CLI: ``python -m repro.analysis [--passes lint,digest,shapes,retrace]``.
+"""CLI: ``python -m repro.analysis [--passes lint,digest,retrace]``.
 
 Exit 0 when every finding is covered by the committed baseline
 (``tools/analysis_baseline.json``); exit 1 on any new finding.  Each
@@ -23,7 +23,7 @@ from repro.analysis.findings import (
     summarize,
 )
 
-PASSES = ("lint", "digest", "shapes", "retrace")
+PASSES = ("lint", "digest", "retrace")
 
 
 def _run_pass(name: str, root: Path) -> list[Finding]:
@@ -35,10 +35,6 @@ def _run_pass(name: str, root: Path) -> list[Finding]:
         from repro.analysis.digest import audit
 
         return audit()
-    if name == "shapes":
-        from repro.analysis.shapes import shape_vmem_audit
-
-        return shape_vmem_audit()
     if name == "retrace":
         from repro.analysis.retrace import retrace_smoke
 
@@ -49,7 +45,7 @@ def _run_pass(name: str, root: Path) -> list[Finding]:
 def main(argv: list[str] | None = None) -> int:
     ap = argparse.ArgumentParser(prog="python -m repro.analysis")
     ap.add_argument(
-        "--passes", default="lint,digest,shapes",
+        "--passes", default="lint,digest",
         help=f"comma-separated subset of {PASSES} (retrace is live "
              "compilation: opt in)",
     )

@@ -2,7 +2,7 @@
 
 A **finding** is one violated invariant, anchored to a source location
 when the pass is static (the AST lints) or to a synthetic location when
-it is semantic (digest audit, shape/VMEM validation, retrace smoke).
+it is semantic (digest audit, retrace smoke).
 Every finding carries:
 
 * a **rule id** (one of ``RULES``) — the invariant class;
@@ -70,15 +70,6 @@ RULES = {
     "digest-identity": (
         "rebuilding the same spec changed its stable_digest: object "
         "identity leaked into the digest, so a new process never hits"
-    ),
-    "shape-mismatch": (
-        "the two delivery lowerings (xla.py, fused.py) disagree on "
-        "output shape/dtype for this layout/monoid: the delivery axis "
-        "is not a pure design choice anymore"
-    ),
-    "vmem-budget": (
-        "the Pallas select-reduce tile ([block_n, block_e, D]) exceeds "
-        "the per-core VMEM budget: this class config cannot run on TPU"
     ),
 }
 

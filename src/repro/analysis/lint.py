@@ -111,10 +111,6 @@ HOT_PATHS: dict[str, tuple[str, ...]] = {
         "Router._dispatch", "Router._on_message", "Router._mark_dead",
         "Router._apply", "Router._fail_pending_if_hopeless",
     ),
-    "kernels/deliver/fused.py": (
-        "deliver_fused_pallas", "deliver_fused_classes",
-        "_combine_kernel",
-    ),
     "kernels/deliver/xla.py": ("deliver_ell_leaf",),
 }
 

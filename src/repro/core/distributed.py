@@ -136,7 +136,7 @@ def _deliver_local(program, out_msg_full, active_full, src, dst, mask,
     partition's padded edge shard.
 
     ``layout``: optional per-shard ``DeliveryLayout`` — routes the
-    monoid path through the fused delivery kernel (dst-sorted CSR over
+    monoid path through fused delivery (degree-class ELL tables over
     THIS shard's edges; the shard mask is folded into the layout), same
     as the local engine's ``delivery='pallas_fused'`` design point.
     """
@@ -298,19 +298,13 @@ def _superstep_sharded(ctx: DistContext, hg_meta, programs, degs,
 def _stack_layouts(layouts):
     """Stack per-partition ``DeliveryLayout``s along a new leading axis
     (the shard_map operand form).  Callers guarantee uniform shapes
-    (one shared class plan, harmonized per-class row/edge/remainder
-    pads); the static grid extents (``class_max_blocks``) and the
-    residual-skip count (``rem_nnz``) take the max so one kernel
-    serves every shard."""
-    shared = dict(
-        rem_nnz=max(l.rem_nnz for l in layouts),
-        class_max_blocks=tuple(
-            map(max, zip(*(l.class_max_blocks for l in layouts)))
-        ),
-    )
+    (one shared class plan, harmonized per-class row and remainder
+    pads); the residual-skip count (``rem_nnz``) takes the max so one
+    program serves every shard."""
+    rem_nnz = max(l.rem_nnz for l in layouts)
     return jax.tree.map(
         lambda *a: jnp.stack(a),
-        *(dataclasses.replace(l, **shared) for l in layouts),
+        *(dataclasses.replace(l, rem_nnz=rem_nnz) for l in layouts),
     )
 
 
@@ -325,10 +319,10 @@ def build_shard_delivery(shard_src, shard_dst, shard_mask,
     and widths are planned ONCE per direction from the merged per-shard
     live-degree histograms — every (shard, destination) pair is a row
     the plan must place, so the DP sees the true row population — and
-    the remaining data-dependent shapes (per-class row counts, edge
-    lengths, remainder pad) are harmonized to per-class maxima across
-    shards.  Cheap bincounts, no throwaway layout build; the resulting
-    layouts stack into one shard_map operand.
+    the remaining data-dependent shapes (per-class row counts,
+    remainder pad) are harmonized to per-class maxima across shards.
+    Cheap bincounts, no throwaway layout build; the resulting layouts
+    stack into one shard_map operand.
     """
     from repro.kernels.deliver import (
         build_delivery_layout,
@@ -356,18 +350,12 @@ def build_shard_delivery(shard_src, shard_dst, shard_mask,
         widths = np.asarray(plan.widths, np.int64)
         n_classes = len(widths)
         rows_max = np.zeros(n_classes, np.int64)
-        nnz_max = np.zeros(n_classes, np.int64)
         rem_max = 0
         for deg in degs:
             cls = classify_degrees(deg, widths)
             pos = cls >= 0
             rows = np.bincount(cls[pos], minlength=n_classes)
-            nnz_c = np.bincount(
-                cls[pos], weights=deg[pos].astype(np.float64),
-                minlength=n_classes,
-            ).astype(np.int64)
             np.maximum(rows_max, rows, out=rows_max)
-            np.maximum(nnz_max, nnz_c, out=nnz_max)
             spill = int(
                 np.maximum(deg[pos] - widths[cls[pos]], 0).sum()
             )
@@ -381,7 +369,6 @@ def build_shard_delivery(shard_src, shard_dst, shard_mask,
                 srcs[p], dsts[p], shard_mask[p], n_src, n_dst,
                 plan=plan,
                 class_rows_pad=class_rows_pad,
-                class_nnz_pad=tuple(int(n) for n in nnz_max),
                 rem_pad_to=rem_pad,
             )
             for p in range(n_parts)

@@ -47,7 +47,7 @@ def deliver(
 
     ``layout``: optional precomputed ``DeliveryLayout`` (the
     ``delivery='pallas_fused'`` design point) — routes the monoid fast
-    path through ``repro.kernels.deliver`` (dst-sorted CSR; gather,
+    path through ``repro.kernels.deliver`` (degree-class ELL; gather,
     mask and combine fused, no ``[nnz, D]`` intermediate).  Custom
     ``reducer``s and per-incidence ``edge_transform``s always take the
     reference path: they consume the materialized rows by contract.
@@ -126,7 +126,7 @@ def superstep_pair(
 
     ``delivery``: optional ``(fwd_layout, bwd_layout)`` pair of
     ``DeliveryLayout``s (vertex->hyperedge, hyperedge->vertex) routing
-    both half-supersteps through the fused delivery kernel.
+    both half-supersteps through fused delivery.
     """
     fwd_layout, bwd_layout = delivery if delivery is not None else (None, None)
     v_ids = jnp.arange(hg.n_vertices, dtype=jnp.int32)

@@ -49,7 +49,6 @@ from repro.kernels.deliver import (
     delivery_structure,
     layout_pair,
     layout_span_args,
-    select_lowering,
 )
 
 from repro.motifs.intersect import INTERSECT_KERNELS
@@ -111,10 +110,10 @@ class ExecutionConfig:
         (``repro.kernels.deliver``) and fuses gather, mask and combine
         so the ``[nnz, D]`` intermediate never hits HBM.  ``auto``
         resolves via ``select_delivery``'s cost model (message width,
-        degree skew via the class plan's padding work, nnz, platform
-        lowering), falling back to ``xla`` for custom ``reducer``s and
-        per-incidence ``edge_transform``s — the non-monoid paths the
-        fused kernel cannot legally take.
+        degree skew via the class plan's padding work, nnz), falling
+        back to ``xla`` for custom ``reducer``s and per-incidence
+        ``edge_transform``s — the non-monoid paths the fused layout
+        cannot legally take.
     """
 
     representation: str = "auto"
@@ -462,11 +461,12 @@ def select_delivery(
 
     Hard gates first: custom ``reducer``s / ``edge_transform``s consume
     materialized per-incidence rows, so they and empty structures take
-    ``xla``.  ``why["lowering"]`` names the lowering the fused path
-    will run (``select_lowering``).  The padding term is the summed
-    work of both directions' degree-class plans at the local builder's
-    row padding (``delivery_structure``; the distributed builder
-    harmonizes pads to shard maxima, so there it is a lower bound).
+    ``xla``.  ``why["lowering"]`` names the fused path's lowering,
+    ``ell`` (``kernels/deliver/xla.py``).  The padding term is the
+    summed work of both directions' degree-class plans at the local
+    builder's row padding (``delivery_structure``; the distributed
+    builder harmonizes pads to shard maxima, so there it is a lower
+    bound).
     Pick fused while (a) that work is within ``FUSED_ELL_WORK_BUDGET``
     slots per incidence, both directions, and (b) the message row is
     within ``FUSED_MAX_WIDTH_BYTES``.  ``skew_gain`` (single-ELL vs
@@ -486,7 +486,7 @@ def select_delivery(
         why["reason"] = "empty structure"
         return "xla", why
 
-    why["lowering"] = select_lowering()
+    why["lowering"] = "ell"
     inputs = structure() if structure is not None else delivery_structure(
         hg.src, hg.dst, hg.e_mask, hg.n_vertices, hg.n_hyperedges
     )
@@ -810,10 +810,9 @@ class Engine:
 
     def _delivery_layouts(self, entry, sp=None):
         """Both directions' fused layouts for one structure, built on
-        its first use for the selected lowering (host dst-sort, ELL
-        pack; the CSR form only for Pallas); their counts go on ``sp``."""
-        lowering = select_lowering()
-        if entry.layouts is None or not entry.layouts[0].serves(lowering):
+        its first use (host dst-sort, ELL pack); their counts go on
+        ``sp``."""
+        if entry.layouts is None:
             hg = entry.hg
             with maybe_span(
                 self.tracer, "engine.layout_build", cat="compile",
@@ -821,8 +820,7 @@ class Engine:
                 n_hyperedges=int(hg.n_hyperedges),
             ):
                 entry.layouts = layout_pair(
-                    hg.src, hg.dst, hg.e_mask, hg.n_vertices,
-                    hg.n_hyperedges, lowering=lowering,
+                    hg.src, hg.dst, hg.e_mask, hg.n_vertices, hg.n_hyperedges
                 )
             self._layout_builds += 1
         if sp is not None:

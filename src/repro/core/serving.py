@@ -713,7 +713,7 @@ class CompiledAlgorithm:
             shard_len_pad = bucket_dim(plan.shard_len)
             shards = _pad_shards(plan, shard_len_pad)
         hgp = base.padded(nv_pad, ne_pad, nnz_pad)
-        # Fused delivery: the dst-sort + ELL/CSR precompute happens HERE,
+        # Fused delivery: the dst-sort + ELL precompute happens HERE,
         # once per (hypergraph, bucket) — the serve loop never re-sorts.
         # Built from the PADDED structure (padding lanes carry e_mask=0
         # and fold to identity), so the layouts match the executable's

@@ -26,15 +26,11 @@ power-of-two ELL width:
   per-class outputs, so results assemble with one gather — never a
   scatter.  Zero-degree destinations (bucket padding!) own no slot at
   all: they point at an appended identity row.
-* Per class, a dense slot-major ``[k_c, rows_c]`` ELL id table (the
-  XLA lowering's vectorized reduce over slots) and, only when the
-  Pallas lowering will run, a CSR-with-tile-bounds edge list of the
-  same dst-sorted edges (the kernel's block-sparse skip, with
-  class-local ``block_e``/grid extents).  The ``ell`` lowering, which every
-  platform selects, never reads the CSR form, so it is not built.
+* Per class, a dense slot-major ``[k_c, rows_c]`` ELL id table: the
+  lowering (``repro.kernels.deliver.xla``) gathers it and reduces over
+  the slot axis.
 * Incidences past a hub's class width land in a small dst-sorted COO
-  residual (XLA lowering only — the Pallas CSR form has no width cap)
-  and take one sorted segment reduce.
+  residual and take one sorted segment reduce.
 
 Statically-dead incidences (``e_mask == 0`` — partition padding, bucket
 padding) are dropped from every packing at build time; only dynamic
@@ -47,7 +43,6 @@ flow through jit / scan / vmap / shard_map as ordinary operands.
 from __future__ import annotations
 
 import dataclasses
-import os
 import time
 from typing import Any
 
@@ -79,19 +74,6 @@ RESIDUAL_WEIGHT = 12.0
 # so padding lanes cost as much as live ones.
 _PAD_FLOOR = 8
 _ROW_FLOOR = 8
-
-
-def select_lowering() -> str:
-    """``ell`` on every platform; ``REPRO_DELIVERY_LOWERING`` overrides."""
-    forced = os.environ.get("REPRO_DELIVERY_LOWERING")
-    if forced:
-        if forced not in ("ell", "pallas", "pallas_interpret"):
-            raise ValueError(
-                "REPRO_DELIVERY_LOWERING must be ell | pallas | "
-                f"pallas_interpret, got {forced!r}"
-            )
-        return forced
-    return "ell"
 
 
 def _pow2_at_least(n: int, floor: int = 1) -> int:
@@ -361,67 +343,33 @@ def classify_degrees(degrees: np.ndarray, widths) -> np.ndarray:
     return np.where(degrees > 0, cls, -1).astype(np.int64)
 
 
-def class_block_e(k: int, block_e: int) -> int:
-    """Class-local Pallas edge-block width: at least the caller's
-    ``block_e``, grown toward the class's ELL width so hub classes
-    amortize grid steps, capped at 1024.
-
-    NOTE the cap is width-blind: for min/max/prod the kernel's
-    ``[block_n, block_e, D]`` select-reduce tile scales with the
-    message width ``D``, so on a REAL TPU a grown hub-class block with
-    wide rows can exceed VMEM (interpret-mode CI cannot catch this) —
-    part of the open TPU-validation item in ROADMAP.md; a D-aware cap
-    needs measured VMEM budgets."""
-    return min(max(int(block_e), _pow2_at_least(int(k))), 1024)
-
-
 @jax.tree_util.register_pytree_node_class
 @dataclasses.dataclass
 class DeliveryLayout:
     """One direction's precomputed fused-delivery layout (degree-classed).
 
     Array children (device arrays; leading dims may gain a partition dim
-    under the distributed executor).  Per degree class ``c`` (tuples of
-    length ``n_classes``):
+    under the distributed executor):
 
-      class_ell[c]: ``[k_c, rows_c]`` int32 — the class's destinations'
-        first-``k_c`` sender ids, slot-major: column ``r`` is the
-        destination in slot ``r`` (identity row ``n_src`` in empty
-        slots).  The XLA lowering's dense table.
-      class_src[c] / class_dst[c]: ``[nnz_c_pad]`` int32 — ALL the
-        class's live incidences in dst-sorted order: sender id and
-        class-LOCAL destination row (padding lanes: identity sender,
-        out-of-range row).  The Pallas kernel's CSR form — no width cap,
-        so the Pallas path needs no residual.
-      class_bounds[c]: ``[n_tiles_c, 2]`` int32 — per output tile of
-        ``block_n`` rows: (first edge block, n edge blocks) at
-        ``class_block_e[c]`` granularity (the block-sparse skip).
-
-    A layout built for the ``ell`` lowering has none of the CSR form:
-    ``class_src``, ``class_dst``, ``class_bounds``, ``class_block_e``
-    and ``class_max_blocks`` are empty (``serves``).
-
-    Shared children:
-
+      class_ell[c]: ``[k_c, rows_c]`` int32, one per degree class — the
+        class's destinations' first-``k_c`` sender ids, slot-major:
+        column ``r`` is the destination in slot ``r`` (identity row
+        ``n_src`` in empty slots).
       inv_perm: ``[n_dst]`` int32 — destination id -> slot in the
         concatenated per-class outputs; zero-degree destinations point
         at the appended identity slot ``sum(class_rows)``.  Assembly is
         one gather — no scatter.
       rem_src / rem_dst: ``[rem_pad]`` int32 — hub incidences past the
         last class width, in dst-sorted COO (padding lanes: identity
-        sender -> last destination).  XLA lowering only; statically
-        skipped when ``rem_nnz == 0``.
+        sender -> last destination).  Statically skipped when
+        ``rem_nnz == 0``.
 
     Static aux: ``n_src``, ``n_dst``, ``nnz`` (real incidences),
     ``rem_nnz`` (real residual), ``class_widths``, ``class_rows``
-    (padded row counts — the array dims), ``block_n``,
-    ``class_block_e``, ``class_max_blocks`` (per-class grid extents).
+    (padded row counts — the array dims).
     """
 
     class_ell: tuple
-    class_src: tuple
-    class_dst: tuple
-    class_bounds: tuple
     inv_perm: jnp.ndarray
     rem_src: jnp.ndarray
     rem_dst: jnp.ndarray
@@ -431,19 +379,12 @@ class DeliveryLayout:
     rem_nnz: int
     class_widths: tuple
     class_rows: tuple
-    block_n: int
-    class_block_e: tuple
-    class_max_blocks: tuple
 
     def tree_flatten(self):
-        children = (
-            self.class_ell, self.class_src, self.class_dst,
-            self.class_bounds, self.inv_perm, self.rem_src, self.rem_dst,
-        )
+        children = (self.class_ell, self.inv_perm, self.rem_src, self.rem_dst)
         aux = (
             self.n_src, self.n_dst, self.nnz, self.rem_nnz,
-            self.class_widths, self.class_rows, self.block_n,
-            self.class_block_e, self.class_max_blocks,
+            self.class_widths, self.class_rows,
         )
         return children, aux
 
@@ -477,50 +418,17 @@ class DeliveryLayout:
     def rem_len(self) -> int:
         return int(self.rem_src.shape[-1])
 
-    def serves(self, lowering: str) -> bool:
-        """Whether this layout holds what ``lowering`` reads: every
-        layout serves ``ell``; only one with the CSR form serves the
-        Pallas lowerings."""
-        return lowering == "ell" or len(self.class_src) == self.n_classes
-
     def shape_signature(self) -> tuple:
         """Hashable shape tuple for the serving executable cache key —
         covers every class-plan-dependent dim, so a degree-regime shift
         within a shape bucket legitimately recompiles."""
         return (
             tuple(tuple(a.shape) for a in self.class_ell),
-            tuple(tuple(a.shape) for a in self.class_src),
-            tuple(tuple(a.shape) for a in self.class_bounds),
             tuple(self.inv_perm.shape),
             tuple(self.rem_src.shape),
-            self.class_widths, self.class_rows, self.class_block_e,
-            self.class_max_blocks, self.rem_nnz,
+            self.class_widths, self.class_rows, self.rem_nnz,
             self.n_src, self.n_dst, self.nnz,
         )
-
-
-def tile_block_bounds(
-    row_offsets: np.ndarray, n_dst_pad: int, block_n: int, block_e: int
-) -> tuple[np.ndarray, int]:
-    """Per-output-tile edge-block ranges from CSR row offsets.
-
-    Tile ``i`` covers destinations ``[i*block_n, (i+1)*block_n)``; its
-    incident edges are CSR rows ``[row_offsets[lo], row_offsets[hi])``,
-    which span edge blocks ``[floor(lo_e/block_e), ceil(hi_e/block_e))``.
-    Boundary blocks contain neighbors' edges; the kernel masks them by
-    destination.  Returns ``([n_tiles, 2] (start, count), max_count)``.
-    """
-    n_tiles = n_dst_pad // block_n
-    bounds = np.zeros((n_tiles, 2), np.int32)
-    n_real = len(row_offsets) - 1
-    for i in range(n_tiles):
-        lo = row_offsets[min(i * block_n, n_real)]
-        hi = row_offsets[min((i + 1) * block_n, n_real)]
-        b_lo = lo // block_e
-        b_hi = -(-hi // block_e)
-        bounds[i] = (b_lo, max(b_hi - b_lo, 0))
-    max_blocks = int(bounds[:, 1].max()) if n_tiles else 0
-    return bounds, max(max_blocks, 1)
 
 
 def build_delivery_layout(
@@ -531,28 +439,22 @@ def build_delivery_layout(
     n_dst: int,
     *,
     plan: ClassPlan | None = None,
-    block_n: int = 128,
-    block_e: int = 256,
     class_rows_pad: tuple | None = None,
-    class_nnz_pad: tuple | None = None,
     rem_pad_to: int | None = None,
-    lowering: str | None = None,
 ) -> DeliveryLayout:
-    """Build one direction's degree-class layout from a concrete
-    incidence list, for ``lowering`` (``select_lowering()`` when None):
-    the per-class CSR edge arrays and tile bounds only for a Pallas
-    lowering, the ELL tables, ``inv_perm`` and residual for every one.
+    """Build one direction's degree-class layout — the ELL tables,
+    ``inv_perm`` and residual — from a concrete incidence list.
 
     ``src``/``dst``/``e_mask`` are host-transferable arrays (``e_mask``
     may be None).  ``plan=None`` lets ``plan_degree_classes`` pick the
     class boundaries and widths from the live-degree histogram; the
     distributed builder passes a shared plan so shard layouts agree.
     By default a class's rows and the residual are padded to a multiple
-    of 8 (``_padded_len``).  ``class_rows_pad`` / ``class_nnz_pad`` /
-    ``rem_pad_to`` force the per-class row counts, edge-array lengths
-    and residual pad (each >= what the incidence needs): per-shard
-    layouts stack into one shard_map operand, and serving's layouts
-    keep bucketed shapes (``layout_pair(bucketed=True)``).
+    of 8 (``_padded_len``).  ``class_rows_pad`` / ``rem_pad_to`` force
+    the per-class row counts and residual pad (each >= what the
+    incidence needs): per-shard layouts stack into one shard_map
+    operand, and serving's layouts keep bucketed shapes
+    (``layout_pair(bucketed=True)``).
     """
     t_build0 = time.perf_counter()
     src = np.asarray(src, np.int64)
@@ -582,10 +484,8 @@ def build_delivery_layout(
     base = np.concatenate([[0], np.cumsum(rows_pad)]).astype(np.int64)
     n_slots = int(base[-1])
     inv_perm = np.full(n_dst, n_slots, np.int64)
-    class_members = []
     for c in range(n_classes):
         members = np.flatnonzero(cls == c)
-        class_members.append(members)
         inv_perm[members] = base[c] + np.arange(len(members))
 
     # One dst-sorted scan feeds every packing.  Stability keeps each
@@ -612,7 +512,7 @@ def build_delivery_layout(
         live_rank = np.zeros(0, np.int64)
         in_ell = over = np.zeros(0, bool)
 
-    # Per-class slot-major ELL tables (XLA lowering).
+    # Per-class slot-major ELL tables.
     class_ell = []
     for c in range(n_classes):
         tbl = np.full((int(widths[c]), rows_pad[c]), n_src, np.int32)
@@ -638,45 +538,8 @@ def build_delivery_layout(
     rem_src[:rem_nnz] = rem_s
     rem_dst[:rem_nnz] = rem_d
 
-    # Per-class dst-sorted CSR edge arrays, built only for a Pallas
-    # lowering: every live incidence of the class — hub tails included,
-    # the CSR form has no width cap.  Padding lanes: identity sender,
-    # out-of-range row.
-    class_src_a, class_dst_a, class_bounds, c_block_e, c_max_blocks = (
-        [], [], [], [], [],
-    )
-    csr = (lowering or select_lowering()) != "ell"
-    for c in range(n_classes if csr else 0):
-        be = class_block_e(int(widths[c]), block_e)
-        sel = s_live & (lane_cls == c) if nnz else np.zeros(0, bool)
-        e_src = s_src[sel]
-        e_dst_local = (inv_perm[s_dst[sel]] - base[c]).astype(np.int32)
-        nnz_c = len(e_src)
-        rows_blk = -(-rows_pad[c] // block_n) * block_n
-        want = nnz_c if class_nnz_pad is None else int(class_nnz_pad[c])
-        assert want >= nnz_c, (want, nnz_c)
-        nnz_c_pad = -(-max(want, 1) // be) * be
-        a_src = np.full(nnz_c_pad, n_src, np.int32)
-        a_dst = np.full(nnz_c_pad, rows_blk, np.int32)
-        a_src[:nnz_c] = e_src
-        a_dst[:nnz_c] = e_dst_local
-        row_counts = np.zeros(rows_pad[c], np.int64)
-        members = class_members[c]
-        row_counts[: len(members)] = live_deg[members]
-        offsets = np.zeros(rows_pad[c] + 1, np.int64)
-        np.cumsum(row_counts, out=offsets[1:])
-        bounds, mb = tile_block_bounds(offsets, rows_blk, block_n, be)
-        class_src_a.append(a_src)
-        class_dst_a.append(a_dst)
-        class_bounds.append(bounds)
-        c_block_e.append(be)
-        c_max_blocks.append(mb)
-
     layout = DeliveryLayout(
         class_ell=tuple(jnp.asarray(t) for t in class_ell),
-        class_src=tuple(jnp.asarray(a) for a in class_src_a),
-        class_dst=tuple(jnp.asarray(a) for a in class_dst_a),
-        class_bounds=tuple(jnp.asarray(b) for b in class_bounds),
         inv_perm=jnp.asarray(inv_perm, jnp.int32),
         rem_src=jnp.asarray(rem_src),
         rem_dst=jnp.asarray(rem_dst),
@@ -686,9 +549,6 @@ def build_delivery_layout(
         rem_nnz=int(rem_nnz),
         class_widths=tuple(int(w) for w in widths),
         class_rows=tuple(int(r) for r in rows_pad),
-        block_n=int(block_n),
-        class_block_e=tuple(c_block_e),
-        class_max_blocks=tuple(c_max_blocks),
     )
     reg = default_registry()
     reg.counter("delivery.layouts_built").inc()
@@ -721,10 +581,10 @@ def layout_pair(
 ) -> tuple[DeliveryLayout, DeliveryLayout]:
     """Both half-superstep directions for one incidence list:
     vertex->hyperedge (combine by ``dst``) and hyperedge->vertex
-    (combine by ``src``); ``kw`` (``lowering`` among them) goes to
-    ``build_delivery_layout``.  ``bucketed`` pads rows and residual to
-    powers of two, so that hypergraphs of one serving bucket share
-    layout shapes and so one executable (``CompiledAlgorithm``)."""
+    (combine by ``src``); ``kw`` goes to ``build_delivery_layout``.
+    ``bucketed`` pads rows and residual to powers of two, so that
+    hypergraphs of one serving bucket share layout shapes and so one
+    executable (``CompiledAlgorithm``)."""
     fwd = build_delivery_layout(
         hg_src, hg_dst, e_mask, n_vertices, n_hyperedges, **kw,
         **(_bucketed_pads(hg_dst, e_mask, n_hyperedges) if bucketed

@@ -1,9 +1,8 @@
 """The fused delivery data path expressed to XLA (sliced-ELL + sorted COO).
 
-Same algorithm as the Pallas class kernels in ``fused`` — mask folded
-into the layout, message rows read once, combine without a serialized
-scatter — but lowered through stock XLA ops.  This is the lowering
-every platform runs, the TPU included:
+Mask folded into the layout, message rows read once, combine without a
+serialized scatter, all through stock XLA ops.  This is delivery's one
+fused lowering, on every platform, the TPU included:
 
 * each degree class's incidences sit in its own dense slot-major
   ``[k_c, rows_c]`` id table: one vectorized gather and one dense

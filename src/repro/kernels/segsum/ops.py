@@ -8,6 +8,30 @@ import numpy as np
 from repro.kernels.segsum.segsum import segsum_pallas
 
 
+def tile_block_bounds(
+    row_offsets: np.ndarray, n_dst_pad: int, block_n: int, block_e: int
+) -> tuple[np.ndarray, int]:
+    """Per-output-tile edge-block ranges from CSR row offsets.
+
+    Tile ``i`` covers destinations ``[i*block_n, (i+1)*block_n)``; its
+    incident edges are CSR rows ``[row_offsets[lo], row_offsets[hi])``,
+    which span edge blocks ``[floor(lo_e/block_e), ceil(hi_e/block_e))``.
+    Boundary blocks contain neighbors' edges; the kernel masks them by
+    destination.  Returns ``([n_tiles, 2] (start, count), max_count)``.
+    """
+    n_tiles = n_dst_pad // block_n
+    bounds = np.zeros((n_tiles, 2), np.int32)
+    n_real = len(row_offsets) - 1
+    for i in range(n_tiles):
+        lo = row_offsets[min(i * block_n, n_real)]
+        hi = row_offsets[min((i + 1) * block_n, n_real)]
+        b_lo = lo // block_e
+        b_hi = -(-hi // block_e)
+        bounds[i] = (b_lo, max(b_hi - b_lo, 0))
+    max_blocks = int(bounds[:, 1].max()) if n_tiles else 0
+    return bounds, max(max_blocks, 1)
+
+
 def segment_sum_mxu(
     msgs: jnp.ndarray,
     dst: jnp.ndarray,
@@ -35,8 +59,6 @@ def segment_sum_mxu(
     tile_bounds = None
     max_blocks = None
     if sorted_dst and e:
-        from repro.kernels.deliver import tile_block_bounds
-
         dst_host = np.asarray(dst)
         assert (np.diff(dst_host) >= 0).all(), (
             "sorted_dst=True needs non-decreasing dst ids (see "

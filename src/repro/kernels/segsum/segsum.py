@@ -23,14 +23,13 @@ Two grid regimes:
   the oracle form.
 * **sorted + block-sparse skip**: for dst-sorted inputs, a
   scalar-prefetched ``[n_tiles, 2]`` bounds table (CSR row offsets at
-  ``block_e`` granularity — ``repro.kernels.deliver.tile_block_bounds``,
-  the same layout product the fused deliver kernel uses) restricts each
+  ``block_e`` granularity, ``ops.tile_block_bounds``) restricts each
   tile to its incident edge blocks, so grid work scales with the tile's
   degree sum instead of nnz.
 
-For the full fused half-superstep (gather + mask + combine in one
-kernel) see ``repro.kernels.deliver`` — this kernel remains the
-combine-only form fed by pre-gathered rows.
+For the full fused half-superstep (gather + mask + combine) see
+``repro.kernels.deliver`` — this kernel is a combine-only form fed by
+pre-gathered rows.
 """
 from __future__ import annotations
 
@@ -112,7 +111,7 @@ def segsum_pallas(
     output tile matches, so they contribute nothing).
 
     ``tile_bounds`` + ``max_blocks`` (from
-    ``repro.kernels.deliver.tile_block_bounds`` over dst-SORTED input)
+    ``repro.kernels.segsum.ops.tile_block_bounds`` over dst-SORTED input)
     enable the block-sparse skip; omitted, the kernel runs the unsorted
     fallback's full j-sweep.
     """

@@ -1,6 +1,6 @@
 """The static-analysis suite checks the checkers.
 
-Positive direction: the four passes run clean on the repo as committed
+Positive direction: the three passes run clean on the repo as committed
 (that is CI's job — here we pin the machinery).  Negative direction
 (the acceptance bar): every pass must catch a deliberately injected
 violation —
@@ -12,10 +12,7 @@ violation —
   (and staying quiet on the warm path), including the ``serve.warm``
   runtime guard;
 * the digest audit flagging an injected collision and an injected
-  identity leak;
-* the shape audit flagging an injected lowering disagreement, and the
-  VMEM model rejecting the worst-geometry wide-row tile (the ROADMAP
-  D>8 caveat, now a checked constraint).
+  identity leak.
 """
 import json
 import os
@@ -23,7 +20,6 @@ import subprocess
 import sys
 import textwrap
 
-import numpy as np
 import pytest
 
 from repro.analysis import (
@@ -353,79 +349,6 @@ def test_digest_stable_across_process_boundary():
     )
     assert out.returncode == 0, out.stderr[-500:]
     assert json.loads(out.stdout) == here
-
-
-# --------------------------------------------------------------------------
-# shape agreement + VMEM budget
-# --------------------------------------------------------------------------
-
-def test_shape_agreement_clean():
-    from repro.analysis.shapes import check_shapes
-
-    assert check_shapes() == []
-
-
-def test_shape_audit_catches_injected_lowering_disagreement():
-    from repro.analysis.shapes import check_shapes
-    from repro.kernels.deliver import _pallas_leaf
-
-    def wrong_dtype(m, layout, monoid, active):
-        out = _pallas_leaf(m, layout, monoid, active, interpret=True)
-        return out.astype(np.int8)         # dtype drift
-
-    found = check_shapes(fused_leaf=wrong_dtype, widths=(1,),
-                         monoids=("min",))
-    assert found and all(f.rule == "shape-mismatch" for f in found)
-
-    def wrong_shape(m, layout, monoid, active):
-        out = _pallas_leaf(m, layout, monoid, active, interpret=True)
-        return out[:-1]                    # drops a destination row
-
-    found = check_shapes(fused_leaf=wrong_shape, widths=(1,),
-                         monoids=("min",))
-    assert found and all(f.rule == "shape-mismatch" for f in found)
-
-
-def test_vmem_model_passes_auto_selectable_widths():
-    """Every gated width fits at a table the kernel can hold; at the
-    full-size dblp table (lane-padded, n_src = 2^20) none does — one
-    reason no auto path selects the Pallas lowering."""
-    from repro.analysis.shapes import (
-        CELL_N_SRC,
-        check_width_gate,
-        shape_vmem_audit,
-        vmem_footprint,
-    )
-    from repro.kernels.deliver import select_lowering
-
-    assert check_width_gate(n_src=4096) == []
-    full = check_width_gate()
-    assert full and all(f.rule == "vmem-budget" for f in full)
-    # D=1 fp32 pads to 128 lanes: the table alone is 512 MiB
-    table = vmem_footprint(block_n=128, block_e=256, d=1, itemsize=4,
-                           n_src=CELL_N_SRC, monoid_name="sum")
-    assert table["msgs_table"] >= 512 * 2**20
-    assert select_lowering() == "ell"
-    assert shape_vmem_audit() == []
-
-
-def test_vmem_model_rejects_wide_rows_at_worst_geometry():
-    """The ROADMAP 'VMEM-check [block_n, block_e, D] at D > 8' caveat as
-    a checked constraint: D=32 fp32 on the hub-class tile cap violates
-    the 16 MiB budget; D=16 (the widest the auto path selects) fits."""
-    import types
-
-    from repro.analysis.shapes import check_vmem, check_width_gate
-
-    hub = types.SimpleNamespace(
-        class_block_e=(1024,), block_n=128, n_src=4096,
-    )
-    assert check_vmem(hub, 16, 4) == []
-    bad = check_vmem(hub, 32, 4)
-    assert bad and bad[0].rule == "vmem-budget"
-    assert "16 MiB" in bad[0].message
-    # a hypothetical wider auto gate would be caught by the gate check
-    assert check_width_gate(width_budget_bytes=256.0, n_src=4096) != []
 
 
 # --------------------------------------------------------------------------

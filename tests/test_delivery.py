@@ -3,11 +3,11 @@ serving.
 
 The tentpole contracts, asserted:
 
-* **Kernel parity** (property-tested): both fused lowerings — the
-  sliced-ELL + sorted-COO XLA form and the per-class Pallas kernels in
-  interpret mode — equal the reference gather/mask/segment path across
-  monoids (sum, min, max, or, prod), dtypes, dead-row masks, dynamic
-  activity, empty segments and padded buckets.  Equality is BITWISE:
+* **Kernel parity** (property-tested): the fused lowering — the
+  sliced-ELL + sorted-COO XLA form — equals the reference
+  gather/mask/segment path across monoids (sum, min, max, or, prod),
+  dtypes, dead-row masks, dynamic activity, empty segments and padded
+  buckets.  Equality is BITWISE:
   order-insensitive monoids (min/max/or) on arbitrary values, sum/prod
   on integer-valued payloads where every association order is exact.
   (Float sums across different reduce algorithms differ by
@@ -18,8 +18,8 @@ The tentpole contracts, asserted:
   histogram and structurally sound (ascending pow2 widths, rows
   conserved, residual == spill past the last width).
 * **Pathological degree histograms**: single mega-hub, uniform, empty,
-  all-overflow (forced width-1 plan), hub-on-shard-boundary — both
-  lowerings, all monoids, bitwise vs the reference; shard-harmonized
+  all-overflow (forced width-1 plan), hub-on-shard-boundary — all
+  monoids, bitwise vs the reference; shard-harmonized
   class plans in the subprocess distributed suite.
 * **Engine seam**: ``delivery='pallas_fused'`` matches ``'xla'``
   end-to-end through ``Engine.run`` and ``Engine.compile``; ``auto``
@@ -122,53 +122,34 @@ def test_fused_delivery_bitwise_equals_reference(case):
         n_dst, prog,
         e_mask=jnp.asarray(mask) if mask is not None else None,
     )
-    layout = build_delivery_layout(
-        src, dst, mask, n_src, n_dst, block_n=8, block_e=16,
-        lowering="pallas_interpret",
-    )
-    for lowering in ("ell", "pallas_interpret"):
-        got = fused_deliver(
-            jnp.asarray(msg), act_j, layout, prog, lowering=lowering
-        )
-        assert np.array_equal(
-            np.asarray(ref), np.asarray(got), equal_nan=True
-        ), (monoid, lowering, msg.dtype)
+    layout = build_delivery_layout(src, dst, mask, n_src, n_dst)
+    got = fused_deliver(jnp.asarray(msg), act_j, layout, prog)
+    assert np.array_equal(
+        np.asarray(ref), np.asarray(got), equal_nan=True
+    ), (monoid, msg.dtype)
 
 
 @given(incidence_case())
 def test_fused_delivery_padded_layout_invariance(case):
-    """Forcing larger per-class row/edge/remainder pads (the shard
-    harmonization path) must not change any result, on either
-    lowering."""
+    """Forcing larger per-class row/remainder pads (the shard
+    harmonization path) must not change any result."""
     src, dst, mask, n_src, n_dst, monoid, msg, active = case
     prog = Program(procedure=lambda *a: None, combiner=monoid)
     act_j = jnp.asarray(active) if active is not None else None
-    base = build_delivery_layout(
-        src, dst, mask, n_src, n_dst, block_n=8, block_e=16,
-        lowering="pallas_interpret",
-    )
+    base = build_delivery_layout(src, dst, mask, n_src, n_dst)
     padded = build_delivery_layout(
-        src, dst, mask, n_src, n_dst, block_n=8, block_e=16,
-        lowering="pallas_interpret",
+        src, dst, mask, n_src, n_dst,
         plan=ClassPlan(
             widths=base.class_widths,
             rows=tuple(int(r) for r in base.class_rows),
             residual=base.rem_nnz,
         ),
         class_rows_pad=tuple(r + 24 for r in base.class_rows),
-        class_nnz_pad=tuple(
-            int(a.shape[0]) + 37 for a in base.class_src
-        ),
         rem_pad_to=base.rem_len + 19,
     )
-    a = fused_deliver(jnp.asarray(msg), act_j, base, prog, lowering="ell")
-    for lowering in ("ell", "pallas_interpret"):
-        b = fused_deliver(
-            jnp.asarray(msg), act_j, padded, prog, lowering=lowering
-        )
-        assert np.array_equal(
-            np.asarray(a), np.asarray(b), equal_nan=True
-        ), lowering
+    a = fused_deliver(jnp.asarray(msg), act_j, base, prog)
+    b = fused_deliver(jnp.asarray(msg), act_j, padded, prog)
+    assert np.array_equal(np.asarray(a), np.asarray(b), equal_nan=True)
 
 
 def test_fused_float_sum_within_reassociation_tolerance():
@@ -184,16 +165,11 @@ def test_fused_float_sum_within_reassociation_tolerance():
         jnp.asarray(msg), None, jnp.asarray(src), jnp.asarray(dst),
         n_dst, prog,
     )
-    layout = build_delivery_layout(
-        src, dst, None, n_src, n_dst, lowering="pallas_interpret"
+    layout = build_delivery_layout(src, dst, None, n_src, n_dst)
+    got = fused_deliver(jnp.asarray(msg), None, layout, prog)
+    np.testing.assert_allclose(
+        np.asarray(ref), np.asarray(got), rtol=1e-5, atol=1e-5
     )
-    for lowering in ("ell", "pallas_interpret"):
-        got = fused_deliver(
-            jnp.asarray(msg), None, layout, prog, lowering=lowering
-        )
-        np.testing.assert_allclose(
-            np.asarray(ref), np.asarray(got), rtol=1e-5, atol=1e-5
-        )
 
 
 def test_plan_ell_width_remainder_rule():
@@ -278,7 +254,7 @@ def test_class_plan_structurally_sound(deg):
 
 
 # --------------------------------------------------------------------------
-# pathological degree histograms, both lowerings, all monoids
+# pathological degree histograms, all monoids
 # --------------------------------------------------------------------------
 
 def _assert_fused_matches_reference(src, dst, mask, n_src, n_dst,
@@ -286,8 +262,7 @@ def _assert_fused_matches_reference(src, dst, mask, n_src, n_dst,
     rng = np.random.default_rng(7)
     if layout is None:
         layout = build_delivery_layout(
-            src, dst, mask, n_src, n_dst, block_n=8, block_e=16,
-            lowering="pallas_interpret", **build_kw,
+            src, dst, mask, n_src, n_dst, **build_kw
         )
     for monoid in MONOIDS_UNDER_TEST:
         if monoid == "or":
@@ -301,14 +276,12 @@ def _assert_fused_matches_reference(src, dst, mask, n_src, n_dst,
             jnp.asarray(dst), n_dst, prog,
             e_mask=jnp.asarray(mask) if mask is not None else None,
         )
-        for lowering in ("ell", "pallas_interpret"):
-            got = fused_deliver(
-                jnp.asarray(msg), jnp.asarray(active), layout, prog,
-                lowering=lowering,
-            )
-            assert np.array_equal(
-                np.asarray(ref), np.asarray(got), equal_nan=True
-            ), (monoid, lowering)
+        got = fused_deliver(
+            jnp.asarray(msg), jnp.asarray(active), layout, prog
+        )
+        assert np.array_equal(
+            np.asarray(ref), np.asarray(got), equal_nan=True
+        ), monoid
     return layout
 
 
@@ -353,28 +326,24 @@ def test_pathological_empty_structures():
     assert layout.ell_slots >= 0 and layout.rem_nnz == 0
     # zero destinations
     lay = build_delivery_layout(
-        np.zeros(0, np.int32), np.zeros(0, np.int32), None, 3, 0,
-        block_n=8, block_e=16,
+        np.zeros(0, np.int32), np.zeros(0, np.int32), None, 3, 0
     )
     prog = Program(procedure=lambda *a: None, combiner="sum")
-    out = fused_deliver(
-        jnp.ones((3, 2), jnp.float32), None, lay, prog, lowering="ell"
-    )
+    out = fused_deliver(jnp.ones((3, 2), jnp.float32), None, lay, prog)
     assert out.shape == (0, 2)
 
 
 def test_pathological_all_overflow_forced_plan():
     """A forced width-1 plan pushes nearly every incidence through the
-    residual sorted-COO path — the XLA lowering's worst case — while
-    the Pallas CSR form absorbs it densely.  Both stay bitwise."""
+    residual sorted-COO path — the lowering's worst case — and it stays
+    bitwise."""
     rng = np.random.default_rng(3)
     n_src, n_dst, nnz = 40, 30, 900
     src = rng.integers(0, n_src, nnz).astype(np.int32)
     dst = rng.integers(0, n_dst, nnz).astype(np.int32)
     layout = build_delivery_layout(
-        src, dst, None, n_src, n_dst, block_n=8, block_e=16,
+        src, dst, None, n_src, n_dst,
         plan=ClassPlan(widths=(1,), rows=(n_dst,), residual=nnz - n_dst),
-        lowering="pallas_interpret",
     )
     assert layout.rem_nnz > 0.9 * nnz
     _assert_fused_matches_reference(
@@ -388,30 +357,22 @@ def test_pathological_zero_degree_destinations_read_identity():
     src = np.array([0, 1, 2, 3], np.int32)
     dst = np.array([2, 2, 2, 2], np.int32)  # only dst 2 is live
     n_src, n_dst = 4, 9
-    layout = build_delivery_layout(
-        src, dst, None, n_src, n_dst, block_n=8, block_e=16,
-        lowering="pallas_interpret",
-    )
+    layout = build_delivery_layout(src, dst, None, n_src, n_dst)
     # every empty destination shares the single identity slot
     inv = np.asarray(layout.inv_perm)
     assert (inv[np.arange(n_dst) != 2] == layout.n_slots).all()
     prog = Program(procedure=lambda *a: None, combiner="min")
     msg = jnp.arange(4, dtype=jnp.float32)
-    for lowering in ("ell", "pallas_interpret"):
-        out = np.asarray(fused_deliver(msg, None, layout, prog,
-                                       lowering=lowering))
-        assert out[2] == 0.0
-        assert np.isposinf(out[np.arange(n_dst) != 2]).all()
+    out = np.asarray(fused_deliver(msg, None, layout, prog))
+    assert out[2] == 0.0
+    assert np.isposinf(out[np.arange(n_dst) != 2]).all()
 
 
-def test_shard_harmonized_class_plans_stack(monkeypatch):
+def test_shard_harmonized_class_plans_stack():
     """``build_shard_delivery``: one merged-histogram plan, per-class
     pads harmonized to maxima — layouts stack, and a hub destination on
-    the shard boundary stays dense on every shard that sees it.  Built
-    for the Pallas lowering, so the CSR arrays stack too."""
+    the shard boundary stays dense on every shard that sees it."""
     from repro.core.distributed import build_shard_delivery
-
-    monkeypatch.setenv("REPRO_DELIVERY_LOWERING", "pallas_interpret")
 
     rng = np.random.default_rng(4)
     n_parts, shard_len = 4, 256
@@ -431,7 +392,6 @@ def test_shard_harmonized_class_plans_stack(monkeypatch):
         for c in range(lay.n_classes):
             assert lay.class_ell[c].shape[0] == n_parts
             assert lay.class_ell[c].shape[1] == lay.class_widths[c]
-            assert lay.class_src[c].shape[0] == n_parts
     # the hub's shard-local degree fits its class width on every shard
     live = np.asarray(mask) != 0
     for p in range(n_parts):
@@ -489,7 +449,7 @@ def test_delivery_auto_resolves_and_reports():
     cfg, _, decision = eng.resolve(shortest_paths_spec(hg, 0, 8))
     why = decision["delivery"]
     assert cfg.delivery == "pallas_fused", why
-    assert why["lowering"] in ("ell", "pallas", "pallas_interpret")
+    assert why["lowering"] == "ell"
     assert "reason" in why and "message_width_bytes" in why
 
     # tiny structures stay on the reference path (overhead-dominated)
@@ -498,15 +458,25 @@ def test_delivery_auto_resolves_and_reports():
     assert cfg2.delivery == "xla"
 
 
-def test_delivery_auto_rejects_wide_messages_on_ell():
-    """Wide message rows flip the ELL cost model back to the reference
-    path (the dense reduce's padding outweighs the scatter win)."""
+@pytest.mark.parametrize("width,dtype,choice", [
+    (16, jnp.float32, "pallas_fused"),   # 64 B: at the gate
+    (17, jnp.float32, "xla"),            # 68 B
+    (32, jnp.bfloat16, "pallas_fused"),  # 64 B
+    (33, jnp.bfloat16, "xla"),           # 66 B
+    (64, jnp.float32, "xla"),            # 256 B
+])
+def test_delivery_auto_rejects_wide_messages_on_ell(width, dtype, choice):
+    """Message rows wider than ``FUSED_MAX_WIDTH_BYTES`` (64 B, in
+    bytes, whatever the dtype) flip the ELL cost model back to the
+    reference path (the dense reduce's padding outweighs the scatter
+    win); rows at the gate stay fused."""
     hg = medium_hypergraph()
     spec = pagerank_spec(hg, iters=4)
-    wide = spec._replace(initial_msg=jnp.zeros((64,), jnp.float32))
-    choice, why = select_delivery(wide, hg)
-    assert choice == "xla"
-    assert "wide" in why["reason"]
+    msg = spec._replace(initial_msg=jnp.zeros((width,), dtype))
+    got, why = select_delivery(msg, hg)
+    assert got == choice, why
+    assert why["message_width_bytes"] == width * jnp.dtype(dtype).itemsize
+    assert ("wide" in why["reason"]) == (choice == "xla")
 
 
 def test_non_monoid_spec_falls_back_and_explicit_raises():
@@ -741,8 +711,8 @@ def test_tight_build_delivers_bitwise_the_pow2_build(graph, combiner):
         msg = jax.tree.map(jnp.asarray, msg)
         active = jnp.asarray(rng.random(n_src) > 0.2)
         for act in (None, active):
-            a = fused_deliver(msg, act, tight, prog, lowering="ell")
-            b = fused_deliver(msg, act, wide, prog, lowering="ell")
+            a = fused_deliver(msg, act, tight, prog)
+            b = fused_deliver(msg, act, wide, prog)
             for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b),
                             strict=True):
                 assert np.array_equal(np.asarray(x), np.asarray(y))

@@ -4,20 +4,22 @@ A friendster-shaped hypergraph (the generator's ``friendster`` regime:
 mean cardinality 14.5, cardinality exponent 1.9, popularity exponent
 2.0) at about 8k vertices and 1.6k hyperedges has a 512-wide hub class
 and a non-empty residual in both directions, so the degree-class and
-sorted-COO paths of ``deliver_ell_leaf`` both run.  The layout builder
-makes only what the selected lowering reads: the ``ell`` build has no
-CSR arrays, and its ELL tables, ``inv_perm`` and residual are bitwise a
-Pallas build's.
+sorted-COO paths of ``deliver_ell_leaf`` both run, with messages as
+wide as the cost model's width gate admits.
 """
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro import algorithms as alg
 from repro.core import Engine
+from repro.core.api import Program
+from repro.core.engine import deliver
+from repro.core.executor import FUSED_MAX_WIDTH_BYTES
 from repro.data import powerlaw_hypergraph
 from repro.data.generators import DATASET_REGIMES
-from repro.kernels.deliver import layout_pair, layout_span_args
+from repro.kernels.deliver import fused_deliver, layout_pair, layout_span_args
 from repro.obs.trace import Tracer
 from repro.reference import pagerank_np
 
@@ -37,11 +39,6 @@ def hg():
     return friendster_like()
 
 
-def _layouts(hg, lowering):
-    return layout_pair(hg.src, hg.dst, hg.e_mask, hg.n_vertices,
-                       hg.n_hyperedges, lowering=lowering)
-
-
 def _assert_close_to_reference(value, hg):
     want = pagerank_np(hg, iters=ITERS)
     for got, ref in zip(value, want):
@@ -49,8 +46,7 @@ def _assert_close_to_reference(value, hg):
                                    atol=1e-7)
 
 
-def test_pagerank_matches_the_float64_reference(hg, monkeypatch):
-    monkeypatch.delenv("REPRO_DELIVERY_LOWERING", raising=False)
+def test_pagerank_matches_the_float64_reference(hg):
     eng = Engine(delivery="pallas_fused")
     res = eng.run(alg.pagerank_spec(hg, iters=ITERS))
     _assert_close_to_reference(res.value, hg)
@@ -59,63 +55,48 @@ def test_pagerank_matches_the_float64_reference(hg, monkeypatch):
     # residual in both directions
     assert max(fwd.class_widths) >= 128
     assert fwd.rem_nnz > 0 and bwd.rem_nnz > 0
-    # the ell build carries no CSR form
-    for lay in (fwd, bwd):
-        assert lay.class_src == lay.class_dst == lay.class_bounds == ()
-        assert lay.serves("ell") and not lay.serves("pallas")
 
 
-@pytest.mark.parametrize("pallas", ["pallas", "pallas_interpret"])
-def test_ell_build_is_bitwise_the_pallas_build(hg, pallas):
-    """What the ``ell`` lowering reads is the same in both builds; only
-    the Pallas build adds the per-class CSR arrays and tile bounds."""
-    for ell, full in zip(_layouts(hg, "ell"), _layouts(hg, pallas)):
-        assert full.serves("pallas") and full.serves("ell")
-        assert len(full.class_src) == full.n_classes
-        assert ell.class_widths == full.class_widths
-        assert ell.class_rows == full.class_rows
-        assert ell.rem_nnz == full.rem_nnz
-        for a, b in zip(ell.class_ell, full.class_ell, strict=True):
-            assert np.array_equal(np.asarray(a), np.asarray(b))
-        for name in ("inv_perm", "rem_src", "rem_dst"):
-            a, b = getattr(ell, name), getattr(full, name)
-            assert a.dtype == b.dtype
-            assert np.array_equal(np.asarray(a), np.asarray(b)), name
-        csr_bytes = sum(a.nbytes for a in jax.tree.leaves(
-            (full.class_src, full.class_dst, full.class_bounds)))
-        assert (sum(a.nbytes for a in jax.tree.leaves(full))
-                == sum(a.nbytes for a in jax.tree.leaves(ell)) + csr_bytes)
+@pytest.mark.parametrize("monoid", ["sum", "min", "max", "or", "prod"])
+def test_ell_delivery_is_bitwise_the_reference_at_the_width_gate(
+        hg, monoid):
+    """Messages as wide as ``FUSED_MAX_WIDTH_BYTES`` admits (16 float32,
+    or 64 bools for ``or``) through both directions' degree classes and
+    residuals: the fused delivery equals the reference ``deliver``
+    bitwise.  Values are exact under any association order (small
+    integers; +-1 for ``prod``)."""
+    rng = np.random.default_rng(5)
+    prog = Program(procedure=lambda *a: None, combiner=monoid)
+    src, dst = np.asarray(hg.src), np.asarray(hg.dst)
+    pair = layout_pair(hg.src, hg.dst, hg.e_mask, hg.n_vertices,
+                       hg.n_hyperedges)
+    directions = ((src, dst, hg.n_vertices, hg.n_hyperedges),
+                  (dst, src, hg.n_hyperedges, hg.n_vertices))
+    for layout, (s, d, n_src, n_dst) in zip(pair, directions, strict=True):
+        assert layout.rem_nnz > 0
+        if monoid == "or":
+            msg = rng.random((n_src, 64)) > 0.5
+        elif monoid == "prod":
+            msg = rng.choice([-1.0, 1.0], (n_src, 16))
+        else:
+            msg = rng.integers(-4, 5, (n_src, 16))
+        if monoid != "or":
+            msg = msg.astype(np.float32)
+        assert msg[0].nbytes == FUSED_MAX_WIDTH_BYTES
+        msg = jnp.asarray(msg)
+        active = jnp.asarray(rng.random(n_src) > 0.2)
+        for act in (None, active):
+            want = deliver(msg, act, jnp.asarray(s), jnp.asarray(d),
+                           n_dst, prog, e_mask=hg.e_mask)
+            got = fused_deliver(msg, act, layout, prog)
+            assert got.dtype == want.dtype
+            assert np.array_equal(np.asarray(got), np.asarray(want))
 
 
-def test_pallas_interpret_builds_its_own_layout_and_matches(monkeypatch):
-    """The structure cache does not serve an ``ell`` build to a Pallas
-    run: the layout is rebuilt with the CSR form, and the answers
-    agree."""
-    small = friendster_like(2000, 400)
-    spec = lambda: alg.pagerank_spec(small, iters=ITERS)
-    monkeypatch.delenv("REPRO_DELIVERY_LOWERING", raising=False)
-    eng = Engine(delivery="pallas_fused")
-    ell = eng.run(spec())
-    assert not eng._structures[-1].layouts[0].serves("pallas")
-    monkeypatch.setenv("REPRO_DELIVERY_LOWERING", "pallas_interpret")
-    got = eng.run(spec())
-    assert eng.cache_stats()["layout_builds"] == 2
-    assert eng._structures[-1].layouts[0].serves("pallas")
-    for a, b in zip(ell.value, got.value):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=1e-5, atol=1e-7)
-    _assert_close_to_reference(got.value, small)
-    # a Pallas build serves ell too: back on ell, nothing is rebuilt
-    monkeypatch.delenv("REPRO_DELIVERY_LOWERING")
-    eng.run(spec())
-    assert eng.cache_stats()["layout_builds"] == 2
-
-
-def test_engine_run_span_records_the_layout_counts(hg, monkeypatch):
+def test_engine_run_span_records_the_layout_counts(hg):
     """On a miss and on a hit, a fused job's ``engine.run`` span carries
     the live incidences, both directions' lanes and the cached pair's
     device bytes; a job through the reference delivery carries none."""
-    monkeypatch.delenv("REPRO_DELIVERY_LOWERING", raising=False)
     tr = Tracer()
     eng = Engine(tracer=tr, delivery="pallas_fused")
     for _ in range(2):

@@ -236,11 +236,8 @@ def test_tpu_backend_selects_ell_through_cost_model(monkeypatch):
     from repro import algorithms as alg
     from repro.core.executor import select_delivery
     from repro.data import make_dataset
-    from repro.kernels.deliver import select_lowering
 
-    monkeypatch.delenv("REPRO_DELIVERY_LOWERING", raising=False)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    assert select_lowering() == "ell"
     hg = make_dataset("dblp", scale=0.01, seed=0)
     delivery, why = select_delivery(alg.pagerank_spec(hg), hg)
     assert why["lowering"] == "ell"
