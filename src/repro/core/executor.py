@@ -47,6 +47,7 @@ from repro.obs.trace import maybe_span
 from repro.kernels.deliver import (
     DELIVERY_MODES,
     delivery_structure,
+    gathered_lanes,
     layout_pair,
     layout_span_args,
 )
@@ -1189,7 +1190,8 @@ class Engine:
         (JAX's ``jax.trace`` / ``jax.lower`` / ``jax.compile`` inside)
         and ``engine.device_wait``; its ``structure_cache`` arg says
         whether this incidence's cache entry was there (``hit``) or not
-        (``miss``); a fused-delivery job adds the ``layout_span_args``.
+        (``miss``); a fused-delivery job adds the ``layout_span_args``
+        and, once its program is traced, ``gathered_lanes``.
         """
         with maybe_span(self.tracer, "engine.run", cat="execute",
                         algorithm=getattr(spec, "name", "anonymous")) as sp:
@@ -1255,6 +1257,9 @@ class Engine:
                         delivery=delivery,
                     )
             t1 = time.perf_counter()
+            if sp is not None and delivery is not None:
+                sp.args.update(gathered_lanes(
+                    delivery, (spec.v_program, spec.he_program)))
             with maybe_span(self.tracer, "engine.device_wait",
                             cat="execute"):
                 jax.block_until_ready(out)

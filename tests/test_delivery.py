@@ -49,6 +49,7 @@ from hypothesis import given, settings, strategies as st
 
 from repro.algorithms import (
     label_propagation_spec,
+    pagerank_entropy_spec,
     pagerank_spec,
     shortest_paths_spec,
 )
@@ -59,10 +60,12 @@ from repro.core.executor import select_delivery
 from repro.core.hypergraph import HyperGraph
 from repro.data import powerlaw_hypergraph
 from repro.obs.trace import Tracer
+from repro.sparse.segment import resolve_monoid
 from repro.kernels.deliver import (
     ClassPlan,
     build_delivery_layout,
     classify_degrees,
+    deliver_ell_leaf,
     fused_deliver,
     layout_pair,
     plan_degree_classes,
@@ -716,6 +719,223 @@ def test_tight_build_delivers_bitwise_the_pow2_build(graph, combiner):
             for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b),
                             strict=True):
                 assert np.array_equal(np.asarray(x), np.asarray(y))
+
+
+# --------------------------------------------------------------------------
+# leaf groups: a message's same-monoid leaves go through the layout once
+# --------------------------------------------------------------------------
+
+@dataclasses.dataclass(frozen=True)
+class _ByLeafType(Program):
+    """A sender whose combiner follows each leaf's dtype and trailing
+    shape (``monoids``: ``((dtype, trailing shape), monoid name)``)."""
+
+    monoids: tuple = ()
+
+    def monoid_for(self, msg_leaf):
+        key = (str(msg_leaf.dtype), tuple(msg_leaf.shape[1:]))
+        return resolve_monoid(dict(self.monoids)[key])
+
+
+# (monoid, dtype, trailing shape) of each leaf of a message
+LEAF_CASES = {
+    "two_sum_f32": (("sum", "float32", ()),) * 2,
+    "three_sum_f32": (("sum", "float32", ()),) * 3,
+    "mixed_monoids_and_dtypes": (
+        ("sum", "float32", ()), ("min", "int32", ()), ("or", "bool", ()),
+        ("sum", "float32", ()), ("min", "int32", ()),
+    ),
+    "trailing_dims": (
+        ("sum", "float32", (3,)), ("max", "float32", (2, 2)),
+        ("sum", "float32", (3,)), ("max", "float32", (2, 2)),
+        ("max", "float32", (2, 2)),
+    ),
+}
+
+
+def _leaf_case(name, n_src, rng, batch=()):
+    """A ``_ByLeafType`` sender and a message of integer-valued leaves
+    (so every sum's association order is exact)."""
+    spec = LEAF_CASES[name]
+    prog = _ByLeafType(
+        procedure=lambda *a: None,
+        monoids=tuple({(d, t): m for m, d, t in spec}.items()),
+    )
+    msg = []
+    for _, dtype, trail in spec:
+        shape = batch + (n_src,) + trail
+        if dtype == "bool":
+            msg.append(jnp.asarray(rng.random(shape) > 0.5))
+        else:
+            msg.append(jnp.asarray(rng.integers(-4, 5, shape).astype(dtype)))
+    n_groups = len({(m, d, t) for m, d, t in spec})
+    return prog, tuple(msg), n_groups
+
+
+def _residual_graph():
+    hg = _friendster_like()
+    return np.asarray(hg.src), np.asarray(hg.dst), hg.n_vertices, \
+        hg.n_hyperedges
+
+
+def _uniform_graph():
+    rng = np.random.default_rng(4)
+    n_src, n_dst = 70, 50
+    dst = np.repeat(np.arange(n_dst), 4).astype(np.int32)
+    return rng.integers(0, n_src, dst.size).astype(np.int32), dst, \
+        n_src, n_dst
+
+
+def _grouping_layout(kind):
+    """``(src, dst, n_src, n_dst, layout)``: ``residual`` has hub
+    incidences past its last class width, ``no_residual`` has none,
+    ``padded`` is the residual graph with extra rows and lanes."""
+    src, dst, n_src, n_dst = (
+        _uniform_graph() if kind == "no_residual" else _residual_graph()
+    )
+    layout = build_delivery_layout(src, dst, None, n_src, n_dst)
+    if kind == "padded":
+        layout = build_delivery_layout(
+            src, dst, None, n_src, n_dst,
+            plan=ClassPlan(widths=layout.class_widths,
+                           rows=_real_rows(dst, n_dst, layout.class_widths),
+                           residual=layout.rem_nnz),
+            class_rows_pad=tuple(r + 24 for r in layout.class_rows),
+            rem_pad_to=layout.rem_len + 19,
+        )
+    assert (layout.rem_nnz > 0) == (kind != "no_residual")
+    return src, dst, n_src, n_dst, layout
+
+
+def _gathers(jaxpr) -> int:
+    n = 0
+    for eqn in jaxpr.eqns:
+        n += eqn.primitive.name == "gather"
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            n += _gathers(sub)
+    return n
+
+
+@pytest.mark.parametrize("layout_kind", ["residual", "no_residual",
+                                         "padded"])
+@pytest.mark.parametrize("with_active", [False, True])
+@pytest.mark.parametrize("case", sorted(LEAF_CASES))
+def test_grouped_delivery_equals_reference_leaf_by_leaf(
+    case, with_active, layout_kind
+):
+    """Each leaf group goes through the layout once (one gather a class
+    table, one ``inv_perm`` assembly, one residual gather, one mask
+    gather a table when ``active`` is given), and every leaf is bitwise
+    what the reference delivers for it."""
+    src, dst, n_src, n_dst, layout = _grouping_layout(layout_kind)
+    rng = np.random.default_rng(17)
+    prog, msg, n_groups = _leaf_case(case, n_src, rng)
+    active = jnp.asarray(rng.random(n_src) > 0.3) if with_active else None
+    ref = deliver(msg, active, jnp.asarray(src), jnp.asarray(dst), n_dst,
+                  prog)
+    got = fused_deliver(msg, active, layout, prog)
+    assert jax.tree.structure(got) == jax.tree.structure(msg)
+    for r, g, m in zip(ref, got, msg, strict=True):
+        assert g.shape == (n_dst,) + m.shape[1:] and g.dtype == m.dtype
+        assert np.array_equal(np.asarray(r), np.asarray(g)), case
+    per_table = 2 if with_active else 1
+    per_group = (per_table * len(layout.class_ell) + 1
+                 + (per_table if layout.rem_nnz else 0))
+    jaxpr = jax.make_jaxpr(
+        lambda m, a, lay: fused_deliver(m, a, lay, prog)
+    )(msg, active, layout).jaxpr
+    assert _gathers(jaxpr) == n_groups * per_group
+
+
+@pytest.mark.parametrize("case", sorted(LEAF_CASES))
+def test_grouped_delivery_under_vmap(case):
+    """Stacking on the minor axis holds under ``vmap`` (``run_batch``):
+    each batch member equals its own reference delivery."""
+    src, dst, n_src, n_dst, layout = _grouping_layout("residual")
+    rng = np.random.default_rng(23)
+    prog, msg, _ = _leaf_case(case, n_src, rng, batch=(3,))
+    active = jnp.asarray(rng.random((3, n_src)) > 0.3)
+    got = jax.vmap(lambda m, a: fused_deliver(m, a, layout, prog))(
+        msg, active)
+    for b in range(3):
+        ref = deliver(tuple(m[b] for m in msg), active[b],
+                      jnp.asarray(src), jnp.asarray(dst), n_dst, prog)
+        for r, g in zip(ref, got, strict=True):
+            assert np.array_equal(np.asarray(r), np.asarray(g[b])), (case, b)
+
+
+@pytest.mark.parametrize("with_active", [False, True])
+@pytest.mark.parametrize("monoid", ["sum", "min", "or"])
+def test_single_leaf_delivery_traces_as_deliver_ell_leaf(monoid,
+                                                         with_active):
+    """A group of one takes ``deliver_ell_leaf`` as it is: no stack,
+    split or reshape, so single-leaf programs keep their program."""
+    *_, n_src, _, layout = _grouping_layout("residual")
+    prog = Program(procedure=lambda *a: None, combiner=monoid)
+    dtype = jnp.bool_ if monoid == "or" else jnp.float32
+    msg = jnp.zeros((n_src,), dtype)
+    active = jnp.ones((n_src,), bool) if with_active else None
+    fused = jax.make_jaxpr(
+        lambda m, a, lay: fused_deliver(m, a, lay, prog))(msg, active,
+                                                          layout)
+    direct = jax.make_jaxpr(
+        lambda m, a, lay: deliver_ell_leaf(m, lay, prog.monoid_for(m), a)
+    )(msg, active, layout)
+    assert str(fused) == str(direct)
+
+
+def _mixed_he_message_spec(hg):
+    """PageRank whose he->v message also carries a bool flag: two leaf
+    groups he->v (the float32 pair, the flag), one v->he."""
+    spec = pagerank_spec(hg, iters=3)
+    he_proc = spec.he_program.procedure
+    v_proc = spec.v_program.procedure
+
+    def hyperedge(step, ids, attr, msg, cards):
+        out = he_proc(step, ids, attr, msg, cards)
+        return out._replace(msg=out.msg + (cards > 2,))
+
+    def vertex(step, ids, attr, msg, deg):
+        return v_proc(step, ids, attr, msg[:2], deg)
+
+    return spec._replace(
+        initial_msg=spec.initial_msg + (jnp.bool_(False),),
+        v_program=Program(procedure=vertex, combiner="sum"),
+        he_program=Program(procedure=hyperedge),
+    )
+
+
+# spec, leaf groups (v->he, he->v) its messages go through the layout in
+GATHERED_SPECS = {
+    "pagerank": (lambda hg: pagerank_spec(hg, iters=3), (1, 1)),
+    "pagerank_entropy": (lambda hg: pagerank_entropy_spec(hg, iters=3),
+                         (1, 1)),
+    "sssp": (lambda hg: shortest_paths_spec(hg, 0, 6), (1, 1)),
+    "mixed_he_message": (_mixed_he_message_spec, (1, 2)),
+}
+
+
+@pytest.mark.parametrize("name", sorted(GATHERED_SPECS))
+def test_engine_run_span_counts_the_gathered_lanes(name):
+    """``delivery_gathered_lanes`` on the ``engine.run`` span: each
+    direction's ELL slots plus residual lanes times the leaf groups its
+    message went through the layout in, on jobs that traced the program
+    (eager, then jitted) and on a job that reuses the jitted one."""
+    hg = medium_hypergraph()
+    make, groups = GATHERED_SPECS[name]
+    spec = make(hg)
+    tracer = Tracer()
+    eng = Engine(delivery="pallas_fused", tracer=tracer)
+    for jit in (False, True, True):
+        jax.block_until_ready(eng.run(spec, jit=jit).value)
+    runs = [s for s in tracer.spans() if s.name == "engine.run"]
+    layouts = eng._structures[-1].layouts
+    want = sum((l.ell_slots + l.rem_len) * g
+               for l, g in zip(layouts, groups, strict=True))
+    assert [s.args["delivery_gathered_lanes"] for s in runs] == [want] * 3
+    # the last job ran the second one's executable without a trace
+    last = [s for s in tracer.spans() if s.t0 >= runs[2].t0]
+    assert not any(s.name == "jax.trace" for s in last)
 
 
 # --------------------------------------------------------------------------

@@ -582,6 +582,9 @@ def test_profiled_run_records_the_engine_span_tree(hg, tmp_path):
         "layout_bytes": sum(
             sum(t.nbytes for t in l.class_ell) + l.inv_perm.nbytes
             + l.rem_src.nbytes + l.rem_dst.nbytes for l in layouts),
+        # v->he sends one leaf, he->v two that go through it as one group
+        "delivery_gathered_lanes": sum(
+            l.ell_slots + l.rem_len for l in layouts),
     }
     children = sorted(
         (s for s in spans if s.parent == run.id), key=lambda s: s.t0

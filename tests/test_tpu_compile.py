@@ -15,6 +15,7 @@ here, since its entries cannot be read back without a chip.
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -160,6 +161,57 @@ def test_superstep_program_size_does_not_follow_the_row_padding(
         ).compile()
         size[name] = len(serialize(compiled)[0])
     assert size["tight"] < 2 * size["pow2"], size
+
+
+_HLO_DEF = re.compile(r"%(\S+) = (\w+)\[([\d,]*)\]\{([^}]*)\}")
+
+
+def test_pagerank_pair_gathers_each_he_to_v_table_once(
+    one_chip, dblp, padded_layouts
+):
+    """PageRank's he->v message ``(w, rank / card)`` is one leaf group:
+    both leaves sit side by side (``[n_src, 2]``) and gather each he->v
+    class table, ``inv_perm`` and the residual once.  At the dblp
+    bucket size the scan holds 13 gathers, where delivering leaf by leaf
+    held 19: v->he's 4 tables, ``inv_perm`` and residual (6), he->v's
+    twice (12), and the hyperedge procedure's weight lookup (1).  The
+    stacked operand keeps an index's two leaves in one ``(2, 128)``
+    tile, rather than padding the 2-wide minor axis to 128 lanes."""
+    from repro import algorithms as alg
+    from repro.core.engine import compute
+
+    dims, (fwd, bwd) = padded_layouts
+    spec = alg.pagerank_spec(dblp, iters=10)
+    hgp = spec.hg0.padded(*dims)
+
+    def program(hgp, layouts):
+        out = compute(
+            hgp, max_iters=10, initial_msg=spec.initial_msg,
+            v_program=spec.v_program, he_program=spec.he_program,
+            delivery=layouts,
+        )
+        return out.v_attr, out.he_attr
+
+    compiled = jax.jit(program).lower(
+        *_abstract((hgp, (fwd, bwd)), one_chip)
+    ).compile()
+    _fits_one_chip(compiled)
+    text = compiled.as_text()
+
+    def once(lay):  # the class tables, inv_perm, the residual
+        return len(lay.class_ell) + 1 + (lay.rem_nnz > 0)
+
+    per_leaf = once(fwd) + 2 * once(bwd) + 1
+    gathers = re.findall(r"= \S+ gather\(%([^,]+),.*slice_sizes=\{([\d,]+)\}",
+                         text)
+    assert (len(gathers), per_leaf) == (per_leaf - once(bwd), 19)
+    defs = {m[0]: m for m in _HLO_DEF.findall(text)}
+    stacked = [op for op, sizes in gathers if sizes == "1,2"]
+    assert len(stacked) == once(bwd)
+    for op in stacked:
+        _, dtype, shape, layout = defs[op]
+        assert (dtype, shape.split(",")[1]) == ("f32", "2"), defs[op]
+        assert layout.startswith("0,1:T(2,128)"), defs[op]
 
 
 def test_sssp_batch16_executable_compiles_for_v5e(
